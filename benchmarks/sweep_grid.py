@@ -147,7 +147,7 @@ def run(quick: bool = False, tile: int = 32, json_path: str = BENCH_JSON,
                           "compile_s": t_cold - t_b,
                           "plan": plan.to_string()}
         if name == "pallas":
-            backends[name]["interpret"] = plan.pallas_interpret
+            backends[name]["interpret"] = res_b.plan.pallas_interpret
         if is_streaming(name):
             # streaming reducers return top-k rows + exact aggregates, not
             # matrices: pin the surviving rows against the numpy reference

@@ -128,9 +128,8 @@ def main():
 
     res_jax = price(cb, grid, plan=ExecPlan("jax"))   # jit'd, accelerator-ready
     print(f"jax backend max relative drift vs numpy: {drift(res_jax):.2e}")
-    # fused Pallas bracket/segment-sum kernel (interpret mode on CPU; the
-    # same kernel compiles for TPU with ExecPlan("pallas",
-    # pallas_interpret=False))
+    # fused Pallas bracket/segment-sum kernel (interpreted on CPU; on a
+    # TPU the same plan compiles it and prices in float32)
     res_pl = price(cb, grid, plan=ExecPlan("pallas"))
     print(f"pallas backend max relative drift vs numpy: {drift(res_pl):.2e}")
     res_chunk = price(cb, grid, plan=ExecPlan(chunk_scenarios=16))

@@ -7,7 +7,7 @@ Usage: PYTHONPATH=src python scripts/top_collectives.py HLO.gz [N] [--sweep]
 
 ``--backend=`` takes the ``ExecPlan.parse`` spec form — a registered
 backend name plus optional options, e.g. ``--backend=jax``,
-``--backend=pallas:interpret=0`` (compile the Mosaic kernel on real TPU),
+``--backend=pallas:interpret=1`` (interpret the kernel even on a TPU),
 ``--backend=jax:vmap=1``; ``--chunk=K`` bounds peak memory to K scenarios
 at a time (big HLO modules have thousands of call-sites).
 """

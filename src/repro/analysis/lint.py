@@ -246,8 +246,8 @@ def _mesh_allowed(rel: str) -> bool:
 
 _MESH_MSG = ("construct device meshes through repro.compat.make_mesh / "
              "device_mesh_1d or repro.launch.mesh (mesh construction is "
-             "confined to those modules; jax.make_mesh appeared in 0.5.x "
-             "and raw Mesh() device ordering differs)")
+             "confined to those modules: they build Auto axis types, "
+             "where jax.make_mesh defaults to Explicit)")
 
 
 @register_rule("compat-drift", allow_paths=("*repro/compat.py",))

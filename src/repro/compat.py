@@ -1,12 +1,11 @@
-"""Compatibility shims for JAX API drift.
+"""Compatibility seam for the JAX API surface this repo depends on.
 
-Supported JAX versions: 0.4.3x (the baked-in toolchain) through current.
+Supported stack: JAX 0.9.0 (jaxlib 0.9.0, libtpu 0.0.34) on Python 3.12.
 
-Policy: when a JAX symbol moves or changes shape between minor versions,
-it gets ONE adapter here and every call site imports it from
-``repro.compat`` — never from the drifting location directly.  That keeps
-version knowledge in a single file and lets CI catch drift early (the
-tier-1 workflow runs against whatever JAX the environment pins).
+Policy: when a JAX symbol moves or changes shape between releases, it gets
+ONE adapter here and every call site imports it from ``repro.compat`` —
+never from the drifting location directly.  That keeps version knowledge
+in a single file.
 
 The policy is machine-enforced: the ``compat-drift`` rule of
 ``python -m repro.lint`` (see :mod:`repro.analysis.lint` and the README's
@@ -16,122 +15,74 @@ allowlisted home, and ``jax.experimental.pallas`` is additionally allowed
 inside ``kernels/``.
 
 Current shims:
-  * ``shard_map`` — ``jax.shard_map`` only exists on newer JAX; on 0.4.x
-    it lives in ``jax.experimental.shard_map`` with a slightly different
-    signature (``check_rep``/``auto`` instead of ``check_vma``/
-    ``axis_names``).
-  * ``axis_size`` — ``jax.lax.axis_size`` only exists on newer JAX; the
-    0.4.x equivalent is the constant-folded ``psum(1, axis)`` idiom.
-  * ``normalize_cost_analysis`` — ``Compiled.cost_analysis()`` returns a
-    *list* of one per-partition dict on JAX 0.4.x and a plain dict on
-    newer releases; ``dict(...)`` on the list form raises ``ValueError``.
-  * ``segment_sum`` — the sweep kernel's jax backend imports it from here
-    so a future relocation out of ``jax.ops`` is a one-line fix.
-  * ``enable_x64`` — scoped double-precision for the sweep kernel's jax
-    backend (``jax.experimental.enable_x64`` today; falls back to flipping
-    the config flag if the experimental context manager goes away).
-  * ``make_mesh`` / ``device_mesh_1d`` — device-mesh construction.
-    ``jax.make_mesh`` only exists on newer 0.4.x releases and its keyword
-    surface keeps moving; explicit ``jax.sharding.Mesh`` construction is
-    the stable fallback.  The ``compat-drift`` lint rule flags
-    ``Mesh``/``make_mesh`` construction anywhere but here and
+  * ``shard_map`` / ``axis_size`` / ``segment_sum`` — re-exported from
+    their JAX homes so a future relocation is a one-line fix.
+  * ``normalize_cost_analysis`` — ``Compiled.cost_analysis()`` as a dict,
+    never an exception (some backends return ``None`` or raise).
+  * ``enable_x64`` — scoped double precision (``jax.enable_x64(True)``)
+    for the sweep kernel's jax backend; the process-global flag is never
+    flipped.
+  * ``make_mesh`` / ``device_mesh_1d`` — device-mesh construction with
+    ``Auto`` axis types.  ``jax.make_mesh`` defaults to ``Explicit`` axes,
+    under which an un-annotated gather over a sharded table (the
+    embedding lookup) is a type error; the model code relies on GSPMD
+    propagation, so every mesh is built here.  The ``compat-drift`` lint
+    rule flags ``Mesh``/``make_mesh`` construction anywhere but here and
     ``launch/mesh.py``, so ALL mesh plumbing stays behind this seam.
+  * ``mesh_in_context`` — whether a mesh is in context, either through
+    ``jax.set_mesh`` or the ``with mesh:`` form the train driver uses.
   * ``pad_to_multiple`` / ``padded_size`` — uneven-shard padding for the
     scenario-axis ``shard_map`` executors (a scenario count that does not
     divide the device count is edge-padded and masked).
 """
 from __future__ import annotations
 
-import contextlib
-
 import jax
 import numpy as np
+from jax.ops import segment_sum  # noqa: F401  (re-export)
 
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map_0_4
-
-    def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
-                  check_vma=None, check_rep=None, auto=frozenset()):
-        """New-style ``jax.shard_map`` signature on 0.4.x JAX.
-
-        ``check_vma`` maps to the old ``check_rep``.  Partial-manual
-        mappings (``axis_names`` a strict subset of the mesh) are lowered
-        with the would-be-auto axes as manual-but-replicated instead: on
-        0.4.x true partial-auto emits a ``PartitionId`` instruction the
-        SPMD partitioner rejects.  Specs stay valid (auto axes may not
-        appear in them) and results are identical — only XLA's automatic
-        sharding over those axes is lost, which is a performance matter,
-        not a correctness one.
-        """
-        auto = frozenset(auto)
-        if axis_names is not None:
-            auto = auto | (frozenset(mesh.axis_names) - frozenset(axis_names))
-        check = check_vma if check_vma is not None else check_rep
-        if check is None:
-            check = not auto
-        return _shard_map_0_4(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=check)
+shard_map = jax.shard_map
+axis_size = jax.lax.axis_size
 
 
-if hasattr(jax.lax, "axis_size"):
-    axis_size = jax.lax.axis_size
-else:
-    def axis_size(axis_name) -> int:
-        """Size of a mapped axis inside shard_map/pmap bodies (0.4.x)."""
-        return jax.lax.psum(1, axis_name)
-
-
-try:
-    from jax.ops import segment_sum
-except ImportError:                                   # pragma: no cover
-    def segment_sum(data, segment_ids, num_segments=None, **kw):
-        import jax.numpy as _jnp
-        out_shape = (num_segments,) + data.shape[1:]
-        return _jnp.zeros(out_shape, data.dtype).at[segment_ids].add(data)
-
-
-if hasattr(jax.experimental, "enable_x64"):
-    enable_x64 = jax.experimental.enable_x64
-else:                                                 # pragma: no cover
-    @contextlib.contextmanager
-    def enable_x64():
-        old = jax.config.jax_enable_x64
-        jax.config.update("jax_enable_x64", True)
-        try:
-            yield
-        finally:
-            jax.config.update("jax_enable_x64", old)
+def enable_x64():
+    """Context manager: double precision inside the ``with`` block only."""
+    return jax.enable_x64(True)
 
 
 def make_mesh(axis_shapes, axis_names, *, devices=None):
-    """A ``jax.sharding.Mesh`` over ``axis_shapes`` on any supported JAX.
+    """A ``jax.sharding.Mesh`` over ``axis_shapes`` with ``Auto`` axes.
 
-    ``jax.make_mesh`` (when present and no explicit ``devices`` are given)
-    picks a performance-aware device order; otherwise the mesh is built
-    explicitly from the first ``prod(axis_shapes)`` devices — the stable
-    construction every 0.4.x release supports.  Raises ``ValueError`` when
-    fewer devices exist than the shape needs (the same contract
-    ``jax.make_mesh`` has).
+    Without ``devices``, ``jax.make_mesh`` picks a performance-aware
+    device order over all devices; with them, the first
+    ``prod(axis_shapes)`` of ``devices`` are used in order.  Raises
+    ``ValueError`` when fewer devices exist than the shape needs.
     """
     shape = tuple(int(s) for s in axis_shapes)
     names = tuple(axis_names)
-    if devices is None and hasattr(jax, "make_mesh"):
-        return jax.make_mesh(shape, names)
-    from jax.sharding import Mesh
-    devs = list(jax.devices()) if devices is None else list(devices)
-    need = int(np.prod(shape)) if shape else 1
-    if need > len(devs):
-        raise ValueError(f"mesh shape {shape} needs {need} devices, "
-                         f"have {len(devs)}")
-    return Mesh(np.asarray(devs[:need]).reshape(shape), names)
+    auto = (jax.sharding.AxisType.Auto,) * len(names)
+    if devices is not None:
+        need = int(np.prod(shape)) if shape else 1
+        devices = list(devices)
+        if need > len(devices):
+            raise ValueError(f"mesh shape {shape} needs {need} devices, "
+                             f"have {len(devices)}")
+        devices = devices[:need]
+    return jax.make_mesh(shape, names, axis_types=auto, devices=devices)
+
+
+def mesh_in_context() -> bool:
+    """Whether a device mesh is in context: ``jax.set_mesh(mesh)`` sets
+    the abstract mesh, the ``with mesh:`` form only the physical one."""
+    from jax._src.mesh import thread_resources
+    return not (jax.sharding.get_abstract_mesh().empty
+                and thread_resources.env.physical_mesh.empty)
 
 
 def device_mesh_1d(n_devices: int | None = None, axis_name: str = "scenarios"):
     """A 1-D mesh over the first ``n_devices`` devices (default: all) —
     the scenario-axis sharding the distributed sweep executor maps over.
-    Emulate multi-host on CPU with
+    Emulate several devices on CPU with
     ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` (set before the
     first jax import)."""
     n = jax.device_count() if n_devices is None else int(n_devices)
@@ -161,13 +112,12 @@ def pad_to_multiple(a, n_pad: int, axis: int = 0):
 
 
 def normalize_cost_analysis(compiled) -> dict:
-    """Return ``compiled.cost_analysis()`` as a plain dict on any JAX.
+    """Return ``compiled.cost_analysis()`` as a plain dict.
 
-    JAX 0.4.x returns ``[{'flops': ..., ...}]`` (one dict per partition);
-    newer JAX returns the dict directly; some backends return ``None`` or
-    raise.  Callers always get a dict (possibly empty) — never an
-    exception — but a *raising* backend is reported via a warning so a
-    run recorded with zeroed flops/bytes is traceable to its cause.
+    Some backends return ``None`` or raise.  Callers always get a dict
+    (possibly empty) — never an exception — but a *raising* backend is
+    reported via a warning so a run recorded with zeroed flops/bytes is
+    traceable to its cause.
     """
     try:
         cost = compiled.cost_analysis()
@@ -176,10 +126,4 @@ def normalize_cost_analysis(compiled) -> dict:
         warnings.warn(f"cost_analysis() failed ({e!r}); "
                       "proceeding with empty cost data", RuntimeWarning)
         return {}
-    if cost is None:
-        return {}
-    if isinstance(cost, (list, tuple)):
-        if not cost:
-            return {}
-        cost = cost[0]
-    return dict(cost)
+    return dict(cost) if cost else {}

@@ -31,7 +31,8 @@ import warnings
 from dataclasses import dataclass, replace
 from typing import Callable
 
-from .sweep_kernel import price_grid_jax, price_grid_numpy, price_grid_pallas
+from .sweep_kernel import (pallas_modes, price_grid_jax, price_grid_numpy,
+                           price_grid_pallas)
 
 #: Sentinel distinguishing "kwarg not passed" from any real value in the
 #: deprecated ``sweep_run(backend=...)``-style signatures.
@@ -105,12 +106,13 @@ class ExecPlan:
         with bit-identical results.  ``None`` = one pass.
       * ``vmap_scenarios`` — (jax only) ``jax.vmap`` the per-scenario
         kernel instead of the broadcasted batch formulation.
-      * ``pallas_interpret`` — (pallas only) run the kernel body in
-        interpret mode (the CPU/CI default); ``False`` compiles the
-        Mosaic kernel on real TPU.
-      * ``x64`` — (jax/pallas) scope the evaluation to double precision
-        via ``repro.compat.enable_x64`` (the parity-pinned default);
-        ``False`` prices in the ambient f32 for accelerator speed.
+      * ``pallas_interpret`` — (pallas only) run the kernel body in the
+        Pallas interpreter.  ``None`` follows the platform: compiled
+        Mosaic on TPU, interpreted elsewhere.
+      * ``x64`` — scope the evaluation to double precision via
+        ``repro.compat.enable_x64``; ``False`` prices in float32.
+        ``None`` is float64 except for the compiled Pallas kernel, which
+        has no float64 and prices in float32.
       * ``devices`` — (distributed only) shard the scenario axis over this
         many devices (``None`` = all visible devices).
       * ``topk`` — (streaming backends) how many best-by-speedup scenarios
@@ -120,13 +122,17 @@ class ExecPlan:
         adaptive frontier-refinement rounds appended after the seed set;
         each round re-samples ``len(seed)`` scenarios around the current
         speedup frontier.
+
+    :meth:`resolved` fills in ``pallas_interpret`` and ``x64``; every
+    executor receives a resolved plan, and a matrix sweep records it on its
+    ``SweepResult``.
     """
 
     backend: str = "numpy"
     chunk_scenarios: int | None = None
     vmap_scenarios: bool = False
-    pallas_interpret: bool = True
-    x64: bool = True
+    pallas_interpret: bool | None = None
+    x64: bool | None = None
     devices: int | None = None
     topk: int = 64
     refine: int = 0
@@ -143,6 +149,14 @@ class ExecPlan:
             raise ValueError(f"topk must be >= 1, got {self.topk}")
         if self.refine < 0:
             raise ValueError(f"refine must be >= 0, got {self.refine}")
+
+    def resolved(self) -> "ExecPlan":
+        """This plan with ``pallas_interpret`` / ``x64`` decided for the
+        platform the sweep runs on (see the field docs)."""
+        if self.backend == "pallas":
+            interpret, x64 = pallas_modes(self.pallas_interpret, self.x64)
+            return replace(self, pallas_interpret=interpret, x64=x64)
+        return replace(self, x64=True if self.x64 is None else self.x64)
 
     def executor(self) -> Callable:
         """The registered ``fn(cb, view, plan)`` for :attr:`backend`."""

@@ -660,9 +660,13 @@ def compile_bundle(bundle: TraceBundle) -> CompiledBundle:
         buffer_bytes=arr(buffer_bytes),
         accesses_per_element=arr(ape), prefetch_frac=arr(pf),
         unpack=np.asarray(unpack, dtype=bool),
-        counters=bundle.counters,
-        sampling_period=bundle.sampling_period,
-        baseline_runtime_ns=bundle.counters.wall_time_ns)
+        # counters recorded as Python ints (memsim sums load counts) would
+        # enter a float32 jit as int32 and overflow past 2**31
+        counters=dataclasses.replace(bundle.counters, **{
+            f.name: float(getattr(bundle.counters, f.name))
+            for f in dataclasses.fields(bundle.counters)}),
+        sampling_period=float(bundle.sampling_period),
+        baseline_runtime_ns=float(bundle.counters.wall_time_ns))
 
 
 # --------------------------------------------------------------------------
@@ -686,6 +690,8 @@ class SweepResult:
     t_transfer_cxl_ns: np.ndarray
     t_access_mpi_ns: np.ndarray
     t_access_cxl_ns: np.ndarray
+    #: the resolved :class:`ExecPlan` the matrices were priced under
+    plan: ExecPlan | None = None
 
     # -- per-call matrices ---------------------------------------------------
     @property
@@ -931,7 +937,7 @@ def _sweep_plan(cb: CompiledBundle, grid, plan: ExecPlan | None,
     chunking, sharding, reduction — and returns its own result type
     (canonically :class:`TopKSweepResult`).
     """
-    plan = plan if plan is not None else ExecPlan()
+    plan = (plan if plan is not None else ExecPlan()).resolved()
     run = resolve_backend(plan.backend)
     if is_streaming(plan.backend):
         return run(cb, grid, plan, mpi_transfer, free_transfer)
@@ -956,7 +962,7 @@ def _sweep_plan(cb: CompiledBundle, grid, plan: ExecPlan | None,
                 for f in MATRIX_FIELDS:
                     mats[f][sl] = np.asarray(part[f], dtype=np.float64)
 
-    return SweepResult(grid=grid, compiled=cb, **mats)
+    return SweepResult(grid=grid, compiled=cb, plan=plan, **mats)
 
 
 def sweep_run(bundle, grid: ParamGrid, mpi_transfer=None, free_transfer=None,
@@ -1217,7 +1223,8 @@ def _sweep_plan_many(bundles, grid, plan: ExecPlan | None, names=None,
         hi = lo + cb.n_calls
         mats = {f: np.ascontiguousarray(getattr(sup, f)[:, lo:hi])
                 for f in MATRIX_FIELDS}
-        results.append(SweepResult(grid=grid, compiled=cb, **mats))
+        results.append(SweepResult(grid=grid, compiled=cb, plan=sup.plan,
+                                   **mats))
         lo = hi
     return MultiSweepResult(grid=grid, results=tuple(results), names=names)
 

@@ -22,8 +22,9 @@ executors:
     in ``repro.kernels.sweep_bracket``: bracket terms are computed and
     segment-reduced in VMEM scratch while tiling the ``(scenarios,
     packed_samples)`` plane, so the ``(S, n_samples)`` intermediates never
-    reach HBM.  ``interpret=True`` (the default) runs the kernel body in
-    Python on CPU — how CI exercises the real kernel.
+    reach HBM.  The kernel is compiled by Mosaic on TPU, where it prices
+    in float32, and interpreted elsewhere (in float64 by default) — how
+    the CPU test suite exercises the real kernel.
 
 The physics stays written once: the bracket formulas live in
 ``access.BracketTerms``/``category_bracket`` and the transfer models expose
@@ -83,10 +84,10 @@ def _segment_sum_np(x: np.ndarray, starts: np.ndarray,
 
 
 def _segment_sum(x, starts, counts, seg_ids, n_seg, xp, impl=None,
-                 interpret=True):
+                 interpret=None):
     """Backend dispatch: reduceat (numpy), ``jax.ops.segment_sum`` (jax),
-    or the tiled Pallas kernel (``impl="pallas"``; ``interpret`` selects
-    the CPU interpret mode vs the compiled Mosaic kernel on TPU).
+    or the tiled Pallas kernel (``impl="pallas"``; ``interpret=None``
+    compiles it on TPU and interprets it elsewhere).
 
     ``x``'s LAST axis is the packed-sample axis; the result replaces it
     with an ``n_seg`` per-site axis.  Both encodings of the segmentation
@@ -169,7 +170,11 @@ def price_grid(cb, view, xp, bracket_terms=None) -> dict:
 
     # -- characterization (same code path as the scalar predictor) ----------
     ch = Characterization.from_counters(cb.counters, v, xp=xp)  # (S, 1)
-    n = xp.maximum(1.0, asx(cb.accesses_per_element))           # (C,)
+    # scenario-independent, so computed on the host in float64: on TPU,
+    # where float64 is emulated by float32 pairs, XLA's simplifier
+    # reassociates the pair sum of a constant and a traced value (the
+    # ``1 - f_first`` below) and drops its low word
+    n = np.maximum(1.0, cb.accesses_per_element)                # (C,)
     f_first = 1.0 / n
     weights = {c: f_first * asx(ch.first[c])
                + (1.0 - f_first) * asx(ch.subsequent[c])
@@ -357,8 +362,24 @@ def _precision_scope(x64: bool):
 # Pallas executor (fused bracket + segment sum)
 # --------------------------------------------------------------------------
 
-def price_grid_pallas(cb, view, interpret: bool = True,
-                      x64: bool = True) -> dict:
+def pallas_modes(interpret: bool | None = None,
+                 x64: bool | None = None) -> tuple:
+    """``(interpret, x64)`` for the Pallas executor, ``None`` resolved
+    from the platform: compiled Mosaic in float32 on TPU, the interpreter
+    in float64 elsewhere.  Mosaic has no 64-bit types, so a compiled
+    kernel at x64 is refused here instead of by the TPU compiler."""
+    from ..kernels import resolve_interpret
+    interpret = resolve_interpret(interpret)
+    x64 = interpret if x64 is None else bool(x64)
+    if x64 and not interpret:
+        raise ValueError("the compiled Pallas sweep kernel has no float64 "
+                         "(Mosaic supports 32-bit types only): price with "
+                         "x64=False, or interpret=True")
+    return interpret, x64
+
+
+def price_grid_pallas(cb, view, interpret: bool | None = None,
+                      x64: bool | None = None) -> dict:
     """Evaluate the grid with the fused Pallas bracket/segment-sum kernel.
 
     Identical to :func:`price_grid_jax` except the four scenario-dependent
@@ -369,9 +390,11 @@ def price_grid_pallas(cb, view, interpret: bool = True,
     packed groups enter in the pallas-friendly padded layout of
     ``CompiledBundle.padded_groups``.
 
-    ``interpret=True`` (default) executes the kernel body in Python on the
-    CPU backend — the CI validation mode; pass ``False`` on real TPU.
+    ``interpret`` / ``x64`` default to the platform (:func:`pallas_modes`):
+    the compiled kernel in float32 on TPU, the interpreter in float64
+    elsewhere (the CPU validation mode).
     """
+    interpret, x64 = pallas_modes(interpret, x64)
     _, jnp = _ensure_jax()
 
     def make_run():

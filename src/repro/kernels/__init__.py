@@ -2,8 +2,10 @@
 
 Each kernel package ships ``<name>.py`` (pl.pallas_call + BlockSpec),
 ``ops.py`` (jitted public wrapper) and ``ref.py`` (pure-jnp oracle).
-Validation on this CPU container runs the kernels in ``interpret=True``
-mode against the oracles; TPU is the deployment target.
+Every ``interpret`` argument defaults to ``None``, which
+:func:`resolve_interpret` turns into the platform's mode: the compiled
+Mosaic kernel on TPU, the Pallas interpreter elsewhere (how the CPU test
+suite runs the real kernel bodies against the oracles).
 
   flash_attention/  blockwise online-softmax attention (GQA, causal)
   mamba_scan/       selective-scan recurrence (channel-blocked, VMEM state)
@@ -12,3 +14,14 @@ mode against the oracles; TPU is the deployment target.
   sweep_bracket/    fused bracket-term + per-site segment sum for the
                     scenario sweep (the ``backend="pallas"`` executor)
 """
+from __future__ import annotations
+
+import jax
+
+
+def resolve_interpret(interpret: bool | None = None) -> bool:
+    """``interpret`` as given, or (``None``) whether the default backend
+    lacks a TPU and so must interpret the kernel instead of compiling it."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return bool(interpret)

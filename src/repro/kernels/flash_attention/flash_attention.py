@@ -24,6 +24,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import resolve_interpret
+
 NEG_INF = -1e30
 
 
@@ -82,7 +84,7 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 @functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
                                              "interpret"))
 def flash_attention_bhsd(q, k, v, causal: bool = True, block_q: int = 128,
-                         block_k: int = 128, interpret: bool = True):
+                         block_k: int = 128, interpret: bool | None = None):
     """q: (BH_q, S, D); k/v: (BH_kv, T, D) with BH_q = BH_kv * g.
 
     Head-major layout — ``ops.flash_attention`` handles the (B, S, H, D)
@@ -119,5 +121,5 @@ def flash_attention_bhsd(q, k, v, causal: bool = True, block_q: int = 128,
             pltpu.VMEM((block_q, 1), jnp.float32),   # running denom l
             pltpu.VMEM((block_q, D), jnp.float32),   # output accumulator
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)

@@ -12,11 +12,11 @@ from .flash_attention import flash_attention_bhsd
 @functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
                                              "interpret"))
 def flash_attention(q, k, v, causal: bool = True, block_q: int = 128,
-                    block_k: int = 128, interpret: bool = True):
+                    block_k: int = 128, interpret: bool | None = None):
     """q: (B, S, Hq, D); k/v: (B, T, Hkv, D) -> (B, S, Hq, D).
 
-    ``interpret=True`` executes the kernel body in Python on CPU (the
-    validation mode for this container); on real TPU pass ``False``.
+    ``interpret=None`` compiles the kernel on TPU and interprets it
+    elsewhere (``repro.kernels.resolve_interpret``).
     """
     B, S, Hq, D = q.shape
     T, Hkv = k.shape[1], k.shape[2]

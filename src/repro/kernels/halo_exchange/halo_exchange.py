@@ -70,10 +70,10 @@ def _ring_exchange_device(strip_lo, strip_hi, axis: str,
                  jax.ShapeDtypeStruct(strip_hi.shape, strip_hi.dtype)]
     return pl.pallas_call(
         functools.partial(_halo_kernel, axis=axis),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY),
-                  pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=[pl.BlockSpec(memory_space=pltpu.ANY),
-                   pl.BlockSpec(memory_space=pltpu.ANY)],
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=[pl.BlockSpec(memory_space=pl.ANY),
+                   pl.BlockSpec(memory_space=pl.ANY)],
         out_shape=out_shape,
         scratch_shapes=[pltpu.SemaphoreType.DMA((2,)),
                         pltpu.SemaphoreType.DMA((2,))],

@@ -21,6 +21,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import resolve_interpret
+
 
 def _scan_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, d_ref,
                  y_ref, h_ref, h_scr, *, chunk: int, n_chunks: int):
@@ -53,7 +55,7 @@ def _scan_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, d_ref,
 
 @functools.partial(jax.jit, static_argnames=("d_block", "chunk", "interpret"))
 def mamba_scan_pallas(x, dt, Bt, Ct, A, D, d_block: int = 256,
-                      chunk: int = 256, interpret: bool = True):
+                      chunk: int = 256, interpret: bool | None = None):
     """x/dt: (B, L, d) f32; Bt/Ct: (B, L, N) f32; A: (d, N); D: (d,).
 
     Returns (y (B, L, d), h_final (B, d, N)).
@@ -87,6 +89,6 @@ def mamba_scan_pallas(x, dt, Bt, Ct, A, D, d_block: int = 256,
             jax.ShapeDtypeStruct((Bsz, d, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((d_block, N), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x.astype(jnp.float32), dt, Bt, Ct, A, D[None, :])
     return y, h

@@ -10,7 +10,7 @@ from .mamba_scan import mamba_scan_pallas
 
 @functools.partial(jax.jit, static_argnames=("d_block", "chunk", "interpret"))
 def mamba_scan(x, dt, Bt, Ct, A, D, d_block: int = 256, chunk: int = 256,
-               interpret: bool = True):
+               interpret: bool | None = None):
     """Selective scan.  See ``mamba_scan_pallas`` for shapes."""
     return mamba_scan_pallas(x, dt, Bt, Ct, A, D, d_block=d_block,
                              chunk=chunk, interpret=interpret)

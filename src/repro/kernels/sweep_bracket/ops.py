@@ -52,7 +52,7 @@ def _pad_group(group, n_pad: int):
                                              "interpret"))
 def fused_bracket_segsum(hit, lfb, miss, delta, cxl_lat, n_seg: int, *,
                          block_s: int = SUBLANE, block_n: int = 512,
-                         interpret: bool = True) -> dict:
+                         interpret: bool | None = None) -> dict:
     """The four scenario-dependent bracket aggregates, fused.
 
     ``hit`` / ``lfb`` / ``miss``: ``(lat, w, seg)`` packed sample triples
@@ -111,7 +111,7 @@ DATAFLOW = _make_dataflow()
 @functools.partial(jax.jit, static_argnames=("n_seg", "block_r", "block_n",
                                              "interpret"))
 def segment_sum_pallas(x, seg_ids, n_seg: int, *, block_r: int = SUBLANE,
-                       block_n: int = 512, interpret: bool = True):
+                       block_n: int = 512, interpret: bool | None = None):
     """Tiled Pallas segment sum: ``x (..., n)`` + sorted-or-not ``seg_ids
     (n,)`` -> ``(..., n_seg)``.  Drop-in for the jax branch of
     ``sweep_kernel._segment_sum`` (empty segments sum to zero; ids are

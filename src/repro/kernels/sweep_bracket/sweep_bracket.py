@@ -30,10 +30,10 @@ length; padding rows carry ``w == 0`` (contributing exactly zero to any
 bracket) and ``seg == 0`` (always in range).  Scenario rows and segment
 columns are padded to tile multiples and sliced off by the wrapper.
 
-``interpret=True`` executes the kernel body in Python on CPU — the
-validation mode for this container (and under ``enable_x64`` it runs in
-full float64, which is how the sweep's parity bound of 1e-9 vs the NumPy
-backend is met).  On real TPU pass ``False``.
+``interpret`` defaults to the platform (``repro.kernels.resolve_interpret``):
+compiled by Mosaic on TPU, where it runs in float32 (Mosaic has no f64);
+interpreted elsewhere, where under ``enable_x64`` it runs in full float64
+(how the sweep's parity bound of 1e-9 vs the NumPy backend is met).
 """
 from __future__ import annotations
 
@@ -43,6 +43,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .. import resolve_interpret
 
 #: TPU tile multiples: last dim is always LANE-wide; the second-to-last is
 #: SUBLANE for float32 (interpret mode does not care, but the layouts are
@@ -60,8 +62,11 @@ def _one_hot(seg, n_seg: int, dtype):
 
 def _scatter(term, hot):
     """(block_s, block_n) @ (block_n, n_seg) — the segment scatter as an MXU
-    contraction, accumulated in the term dtype."""
+    contraction, accumulated in the term dtype.  ``HIGHEST`` keeps f32 terms
+    at f32 accuracy on the MXU (the default single bf16 pass would cost
+    about three decimal digits of the sweep's 1e-5 f32 bound)."""
     return jax.lax.dot_general(term, hot, (((1,), (0,)), ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
                                preferred_element_type=term.dtype)
 
 
@@ -110,7 +115,8 @@ def _bracket_kernel(hl_ref, hw_ref, hs_ref, ll_ref, lw_ref, ls_ref,
 
 
 def bracket_segsum_padded(hit, lfb, miss, delta, cxl_lat, n_seg_pad: int, *,
-                          block_s: int, block_n: int, interpret: bool = True):
+                          block_s: int, block_n: int,
+                          interpret: bool | None = None):
     """Raw ``pl.pallas_call`` over pre-padded operands.
 
     ``hit``/``lfb``/``miss``: ``(lat, w, seg)`` triples, each ``(1, n_pad)``
@@ -139,7 +145,7 @@ def bracket_segsum_padded(hit, lfb, miss, delta, cxl_lat, n_seg_pad: int, *,
         out_specs=[out] * 4,
         out_shape=[jax.ShapeDtypeStruct((s_pad, n_seg_pad), delta.dtype)] * 4,
         scratch_shapes=[acc] * 4,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(*hit, *lfb, *miss, delta, cxl_lat)
 
 
@@ -165,7 +171,7 @@ def _segsum_kernel(x_ref, seg_ref, o_ref, acc, *, n_seg_pad: int,
 
 
 def segsum_padded(x, seg, n_seg_pad: int, *, block_r: int, block_n: int,
-                  interpret: bool = True):
+                  interpret: bool | None = None):
     """Raw tiled segment sum: ``x (r_pad, n_pad)`` + ``seg (1, n_pad)`` int32
     -> ``(r_pad, n_seg_pad)``.  Same padding contract as
     :func:`bracket_segsum_padded` (zero-padded ``x``, id-0 padded ``seg``)."""
@@ -181,5 +187,5 @@ def segsum_padded(x, seg, n_seg_pad: int, *, block_r: int, block_n: int,
         out_specs=pl.BlockSpec((block_r, n_seg_pad), lambda ri, ni: (ri, 0)),
         out_shape=jax.ShapeDtypeStruct((r_pad, n_seg_pad), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_r, n_seg_pad), x.dtype)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, seg)

@@ -14,6 +14,7 @@ from ..configs import get_arch
 from ..models import factory
 from ..serve.engine import ServeEngine
 from ..serve.scheduler import ContinuousEngine, ServeStats
+from .compile_cache import use_compile_cache
 
 
 def _price_deployment(engine, plan_spec: str, **compile_kwargs) -> None:
@@ -68,6 +69,7 @@ def main(argv=None) -> int:
                     help="ExecPlan spec for --price-sweep, e.g. 'jax' or "
                          "'pallas:interpret=0' (see ExecPlan.parse)")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.reduced:
@@ -76,7 +78,7 @@ def main(argv=None) -> int:
         raise SystemExit("serve driver supports token-LM archs; "
                          "multimodal decode is exercised by the tests")
     model = factory.make_model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
+    params = jax.jit(model.init)(jax.random.PRNGKey(0))
     max_len = args.prompt_len + args.new_tokens
     prompt = jax.random.randint(jax.random.PRNGKey(1),
                                 (args.batch, args.prompt_len), 0,

@@ -22,6 +22,7 @@ import numpy as np
 
 from ..configs import get_arch
 from ..models import factory
+from .compile_cache import use_compile_cache
 from .mesh import make_mesh
 from ..models.config import ShapeConfig
 from ..parallel import batch_pspecs, named, param_pspecs, zero1_pspecs
@@ -78,8 +79,8 @@ def train(cfg, shape: ShapeConfig, mesh, n_steps: int,
             in_shardings=(pshard, oshard, bshard),
             out_shardings=(pshard, oshard, None),
             donate_argnums=(0, 1))
-        batch_fn = jax.jit(data.batch, out_shardings=bshard,
-                           static_argnums=0)
+        # the step is traced, not static: one compile for every step
+        batch_fn = jax.jit(data.batch, out_shardings=bshard)
 
         history = []
         t0 = time.time()
@@ -153,6 +154,7 @@ def main(argv=None) -> int:
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--fail-at-step", type=int, default=None)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.reduced:

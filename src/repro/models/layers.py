@@ -8,6 +8,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from ..compat import mesh_in_context
 from .config import ArchConfig
 
 
@@ -220,13 +221,12 @@ def _expand_and_pin_heads(q, k, v, cfg: ArchConfig):
     # (B4 — constraining the pre-expansion K/V to replicated instead was
     # tried and REFUTED: GSPMD propagated the replication into the
     # surrounding layer and wire went up 49%; see EXPERIMENTS.md §Perf.)
+    if not mesh_in_context():
+        return q, k, v          # single device: nothing to pin
     spec = P(None, None, "model", None)
-    try:
-        q = jax.lax.with_sharding_constraint(q, spec)
-        k = jax.lax.with_sharding_constraint(k, spec)
-        v = jax.lax.with_sharding_constraint(v, spec)
-    except Exception:
-        pass                    # no mesh context (single-device tests)
+    q = jax.lax.with_sharding_constraint(q, spec)
+    k = jax.lax.with_sharding_constraint(k, spec)
+    v = jax.lax.with_sharding_constraint(v, spec)
     return q, k, v
 
 

@@ -50,8 +50,9 @@ def test_stencil_backends_match_reference():
 def test_hpcg_cg_converges_distributed():
     run_with_devices("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.compat import make_mesh
         from repro.apps.hpcg.jax_impl import make_cg, make_problem
-        mesh = jax.make_mesh((4,), ("z",))
+        mesh = make_mesh((4,), ("z",))
         b = make_problem((16, 16, 16))
         for backend in ("message_based", "message_free"):
             cg = make_cg(mesh, backend, n_iter=30)
@@ -65,11 +66,12 @@ def test_hpcg_cg_converges_distributed():
 def test_message_free_window_matches_ppermute_oracle():
     run_with_devices("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.compat import make_mesh
         from functools import partial
         from jax.sharding import PartitionSpec as P
         from repro.comm import message_based, message_free
         from repro.compat import shard_map
-        mesh = jax.make_mesh((4,), ("z",))
+        mesh = make_mesh((4,), ("z",))
         x = jnp.arange(4 * 6 * 5.0).reshape(4 * 6, 5)
 
         def body(comm, block):
@@ -90,19 +92,20 @@ def test_elastic_checkpoint_restore_across_meshes(tmp_path):
     """Save sharded on a (1,4) mesh; restore onto (2,2) — elastic restart."""
     run_with_devices(f"""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.compat import make_mesh
         from repro.configs import ARCHS
         from repro.models.factory import make_model
         from repro.parallel import param_pspecs, named
         from repro.train import checkpoint as ckpt
         cfg = ARCHS["qwen2.5-3b"].reduced()
         model = make_model(cfg)
-        mesh1 = jax.make_mesh((1, 4), ("data", "model"))
+        mesh1 = make_mesh((1, 4), ("data", "model"))
         with mesh1:
             params = jax.jit(model.init, out_shardings=named(
                 mesh1, param_pspecs(model.init(jax.random.PRNGKey(0))))
                 )(jax.random.PRNGKey(0))
         ckpt.save({str(tmp_path)!r}, 3, params)
-        mesh2 = jax.make_mesh((2, 2), ("data", "model"))
+        mesh2 = make_mesh((2, 2), ("data", "model"))
         shards = named(mesh2, param_pspecs(params))
         restored, _ = ckpt.restore({str(tmp_path)!r}, 3,
                                    jax.eval_shape(lambda: params), shards)
@@ -118,6 +121,7 @@ def test_sharded_train_step_runs():
     keeps param shardings."""
     run_with_devices("""
         import jax, jax.numpy as jnp
+        from repro.compat import make_mesh
         from repro.configs import ARCHS
         from repro.models.config import ShapeConfig
         from repro.models.factory import make_inputs, make_model
@@ -128,7 +132,7 @@ def test_sharded_train_step_runs():
         from jax.sharding import PartitionSpec as P
         cfg = ARCHS["qwen2.5-3b"].reduced()
         shape = ShapeConfig("t", "train", 64, 4)
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         model = make_model(cfg, moe_impl="dense",
                            act_pspec=P(("data",), None, None))
         with mesh:
@@ -155,6 +159,7 @@ def test_ep_local_moe_matches_dense_on_mesh():
     """EP-local MoE == dense dispatch on a real 2x4 mesh (no-drop capacity)."""
     run_with_devices("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.compat import make_mesh
         from repro.configs import ARCHS
         from repro.models.factory import make_model, make_inputs
         from repro.models.config import ShapeConfig
@@ -163,7 +168,7 @@ def test_ep_local_moe_matches_dense_on_mesh():
             capacity_factor=8.0)
         batch = make_inputs(cfg, ShapeConfig("t", "train", 64, 2),
                             abstract=False)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         with mesh:
             params = make_model(cfg).init(jax.random.PRNGKey(0))
             params = jax.device_put(params, named(mesh, param_pspecs(params)))
@@ -186,6 +191,7 @@ def test_pipeline_parallel_matches_sequential():
     backward (autodiff through the wavefront)."""
     run_with_devices("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.compat import make_mesh
         from jax.sharding import PartitionSpec as P
         from repro.compat import shard_map
         from repro.parallel.pipeline import pipeline_apply
@@ -199,7 +205,7 @@ def test_pipeline_parallel_matches_sequential():
             out, _ = jax.lax.scan(body, x, w_stack)
             return out
         ref = jax.vmap(lambda x: block_fn(ws, x))(xs)
-        mesh = jax.make_mesh((2, 2), ("pod", "data"))
+        mesh = make_mesh((2, 2), ("pod", "data"))
         f = shard_map(
             lambda w, x: pipeline_apply(w, x, block_fn, axis="pod"),
             mesh=mesh, in_specs=(P("pod"), P()), out_specs=P(),
@@ -302,11 +308,12 @@ def test_compressed_psum_error_feedback():
     step; error feedback keeps the RUNNING SUM unbiased over steps."""
     run_with_devices("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.compat import make_mesh
         from functools import partial
         from jax.sharding import PartitionSpec as P
         from repro.compat import shard_map
         from repro.parallel.pipeline import compressed_psum
-        mesh = jax.make_mesh((4,), ("dp",))
+        mesh = make_mesh((4,), ("dp",))
         xs = jax.random.normal(jax.random.PRNGKey(0), (5, 4, 64))  # 5 steps
 
         def steps(xs):

@@ -48,7 +48,28 @@ def grid():
 def test_defaults():
     p = ExecPlan()
     assert (p.backend, p.chunk_scenarios, p.vmap_scenarios,
-            p.pallas_interpret, p.x64) == ("numpy", None, False, True, True)
+            p.pallas_interpret, p.x64) == ("numpy", None, False, None, None)
+    # unset precision and mode resolve from the platform (CPU here)
+    assert p.resolved().x64 is True
+    r = ExecPlan(backend="pallas").resolved()
+    assert (r.pallas_interpret, r.x64) == (True, True)
+
+
+def test_pallas_plan_resolves_from_backend(monkeypatch):
+    """The Pallas plan follows ``jax.default_backend()``: compiled in
+    float32 on TPU, interpreted in float64 elsewhere; explicit choices
+    win, and a compiled float64 kernel (which Mosaic cannot build) is
+    refused up front."""
+    import jax
+    assert ExecPlan(backend="pallas").resolved().pallas_interpret is True
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    r = ExecPlan(backend="pallas").resolved()
+    assert (r.pallas_interpret, r.x64) == (False, False)
+    assert ExecPlan(backend="jax").resolved().x64 is True
+    r = ExecPlan(backend="pallas", pallas_interpret=True).resolved()
+    assert (r.pallas_interpret, r.x64) == (True, True)
+    with pytest.raises(ValueError, match="no float64"):
+        ExecPlan(backend="pallas", x64=True).resolved()
 
 
 def test_validation():
@@ -154,9 +175,10 @@ def test_register_custom_backend_runs_through_price(cb, grid):
         ref = price(cb, grid)
         np.testing.assert_array_equal(res.gain_ns, ref.gain_ns)
         # chunking wraps ANY registered backend: one call per scenario,
-        # each handed the active plan
+        # each handed the active plan, resolved for the platform
         assert len(calls) == len(grid)
-        assert all(p is plan for p in calls)
+        assert all(p == plan.resolved() for p in calls)
+        assert res.plan == plan.resolved()
         # parse sees it too — the registry is the single source of truth
         assert "traced_numpy" in known_backends()
         assert ExecPlan.parse("traced_numpy").backend == "traced_numpy"

@@ -29,11 +29,12 @@ def run_with_devices(code: str, n: int = 8, timeout: int = 1200):
 def test_build_step_compiles_all_kinds(arch):
     run_with_devices(f"""
         import jax
+        from repro.compat import make_mesh
         from repro.configs import ARCHS
         from repro.models.config import ShapeConfig
         from repro.launch.dryrun import build_step
         cfg = ARCHS[{arch!r}].reduced()
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         shapes = [ShapeConfig("t", "train", 64, 8),
                   ShapeConfig("p", "prefill", 64, 8),
                   ShapeConfig("d", "decode", 64, 8)]
@@ -50,11 +51,12 @@ def test_dryrun_cell_record_schema():
     """run_cell emits the full record schema the benchmarks consume."""
     out = run_with_devices("""
         import jax, json
+        from repro.compat import make_mesh
         from repro.configs import ARCHS
         from repro.models.config import ShapeConfig
         from repro.launch.dryrun import run_cell
         cfg = ARCHS["qwen2.5-3b"].reduced()
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         rec = run_cell(cfg, ShapeConfig("train_4k", "train", 64, 8), mesh)
         for key in ("roofline", "memory", "collectives", "analytic",
                     "cost_raw", "compile_s"):
@@ -66,3 +68,21 @@ def test_dryrun_cell_record_schema():
         print("record schema OK")
     """)
     assert "record schema OK" in out
+
+
+def test_mesh_seam_builds_auto_axes():
+    """Every mesh comes from the one seam with Auto axis types: under
+    jax.make_mesh's Explicit default, the vocab-sharded embedding gather
+    is a sharding type error."""
+    import jax
+    from jax.sharding import AxisType
+
+    from repro.compat import device_mesh_1d, make_mesh
+    from repro.launch.mesh import make_mesh as launch_make_mesh
+    for mesh in (make_mesh((1, 1), ("data", "model")),
+                 make_mesh((1,), ("z",), devices=jax.devices()),
+                 launch_make_mesh((1, 1), ("data", "model")),
+                 device_mesh_1d(1)):
+        assert set(mesh.axis_types) == {AxisType.Auto}
+    with pytest.raises(ValueError, match="needs 2 devices"):
+        make_mesh((2,), ("z",), devices=jax.devices()[:1])

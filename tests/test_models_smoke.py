@@ -75,6 +75,25 @@ def test_prefill_then_decode_matches_forward(name):
                                atol=2e-2, rtol=2e-2)
 
 
+@pytest.mark.parametrize("name", ["qwen2.5-3b", "phi3-medium-14b"])
+def test_float32_reference_matches_forward(name):
+    """The plain float32 reference (models/reference.py) and the model's
+    forward agree at every queried position of a float32 reduced config."""
+    from repro.models.reference import reference_logits
+    cfg = ARCHS[name].reduced()
+    model = make_model(cfg)
+    params = model.init(KEY)
+    toks = jax.random.randint(jax.random.PRNGKey(2), (2, 24), 0,
+                              cfg.vocab_size)
+    at = jnp.asarray([[5, 23], [0, 17]], jnp.int32)
+    got = jax.jit(lambda p, t, a: reference_logits(cfg, p, t, a))(
+        params, toks, at)
+    full, _ = model.forward(params, {"tokens": toks})
+    want = jnp.take_along_axis(full, at[:, :, None], axis=1)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=1e-4, rtol=1e-4)
+
+
 def test_moe_dense_scatter_equivalence():
     cfg = ARCHS["phi3.5-moe-42b-a6.6b"].reduced()
     batch = make_inputs(cfg, TRAIN, abstract=False)

@@ -141,8 +141,9 @@ def test_async_checkpointer(tmp_path):
 def test_restart_resumes_exact_stream(tmp_path):
     """Fault-tolerance contract: restore + deterministic data reproduce
     the uninterrupted run exactly."""
+    from repro.launch.mesh import make_mesh
     from repro.launch.train import train
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     shape = ShapeConfig("t", "train", 32, 4)
     # uninterrupted run
     p_ref, hist_ref = train(CFG, shape, mesh, 9, ckpt_dir=None, log_every=1)
