@@ -18,6 +18,7 @@ Usage:  PYTHONPATH=src python -m benchmarks.serve_throughput \
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 import time
 
@@ -142,9 +143,11 @@ def run(quick: bool = False, arch: str = "qwen2.5-3b", paged: bool = False,
         "static": {"wall_s": dt, "tok_s": static_tok_s},
         "continuous": {"engine": engine_name, "wall_s": dt_c,
                        "tok_s": cont_tok_s, "greedy_parity": parity,
-                       **cont.stats.as_dict()},
+                       "occupancy": cont.stats.occupancy,
+                       **dataclasses.asdict(cont.stats)},
         "staggered": {"wall_s": dt_s, "tok_s": n_tok_s / dt_s,
-                      **stag.stats.as_dict()},
+                      "occupancy": stag.stats.occupancy,
+                      **dataclasses.asdict(stag.stats)},
         "loadgen": {"workload": wl.meta, "wall_s": dt_l, "slo_ms": SLO_MS,
                     **report.as_dict()},
         "advisor": {"steps": list(priced.names),

@@ -33,6 +33,7 @@ from .execplan import ExecPlan
 from .sweep import (CATEGORICAL_AXES, ParamGrid, SweepAggregates,
                     TopKSweepResult, _axis_values, _chunk_slices,
                     _ParamArrays, _scenario_view, _sweep_plan)
+from .spans import span
 from .sweep_kernel import (DIST_CHUNK_DEFAULT, SPEEDUP_HIST_EDGES,
                            price_topk_chunk)
 
@@ -377,8 +378,10 @@ def run_distributed(cb, scenarios, plan: ExecPlan, *, mpi_transfer=None,
             idx = np.empty(n_pad, dtype=np.int64)
             idx[:size] = offset + np.arange(sl.start, sl.stop)
             idx[size:] = idx[size - 1]       # padded copies, masked out
-            state.add(price_topk_chunk(cb, vs, valid, idx, k,
-                                       n_devices=n_dev, x64=plan.x64))
+            out = price_topk_chunk(cb, vs, valid, idx, k,
+                                   n_devices=n_dev, x64=plan.x64)
+            with span("repro.price.merge", rows=size):
+                state.add(out)
 
     consume(total, 0)
     for r in range(plan.refine):
@@ -390,9 +393,10 @@ def run_distributed(cb, scenarios, plan: ExecPlan, *, mpi_transfer=None,
         total = ArraySet.concat(total, fresh)
 
     top_idx, top_val = state.topk()
-    exact = _sweep_plan(cb, total.subset(top_idx),
-                        ExecPlan(backend="jax", x64=plan.x64),
-                        mpi_transfer, free_transfer)
+    with span("repro.price.exact", rows=len(top_idx)):
+        exact = _sweep_plan(cb, total.subset(top_idx),
+                            ExecPlan(backend="jax", x64=plan.x64),
+                            mpi_transfer, free_transfer)
     return TopKSweepResult(scenarios=total, indices=top_idx,
                            speedups=top_val, result=exact,
                            aggregates=state.aggregates(), plan=plan,

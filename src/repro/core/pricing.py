@@ -32,6 +32,7 @@ from collections.abc import Mapping, Sequence
 
 from .execplan import ExecPlan
 from .params import ModelParams
+from .spans import span
 from .sweep import (CompiledBundle, MultiSweepResult, ParamGrid, SweepResult,
                     _sweep_plan, _sweep_plan_many, compile_bundle)
 from .traces import TraceBundle
@@ -113,14 +114,17 @@ def price(subject, scenarios, plan: ExecPlan | str | None = None,
 
     single = isinstance(subject, (TraceBundle, CompiledBundle, str)) \
         or hasattr(subject, "as_text")
+    backend = plan.backend if plan is not None else ExecPlan.backend
     if single:
         if names is not None:
             raise ValueError("names= labels multi-subject pricing; this "
                              "subject prices to a single SweepResult")
-        cb = _lower(subject, get_advisor)
-        if isinstance(cb, TraceBundle):
-            cb = compile_bundle(cb)
-        return _sweep_plan(cb, grid, plan, mpi_transfer, free_transfer)
+        with span("repro.price", backend=backend, bundles=1,
+                  scenarios=len(grid)):
+            cb = _lower(subject, get_advisor)
+            if isinstance(cb, TraceBundle):
+                cb = compile_bundle(cb)
+            return _sweep_plan(cb, grid, plan, mpi_transfer, free_transfer)
 
     if hasattr(subject, "compiled_steps"):           # serve engine
         subject = subject.compiled_steps()
@@ -132,6 +136,8 @@ def price(subject, scenarios, plan: ExecPlan | str | None = None,
         items = list(subject)
     else:
         return _lower(subject, get_advisor)          # raises the TypeError
-    bundles = [_lower(it, get_advisor) for it in items]
-    return _sweep_plan_many(bundles, grid, plan, names,
-                            mpi_transfer, free_transfer)
+    with span("repro.price", backend=backend, bundles=len(items),
+              scenarios=len(grid)):
+        bundles = [_lower(it, get_advisor) for it in items]
+        return _sweep_plan_many(bundles, grid, plan, names,
+                                mpi_transfer, free_transfer)

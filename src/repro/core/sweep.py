@@ -71,6 +71,7 @@ from .execplan import (_UNSET, ExecPlan, is_streaming, legacy_plan,
                        resolve_backend)
 from .params import ModelParams, Thresholds
 from .predictor import CallPrediction
+from .spans import span
 from .sweep_kernel import MATRIX_FIELDS, SPEEDUP_HIST_EDGES
 from .traces import TraceBundle
 from .transfer import TRANSFER_MODELS, SiteTraffic
@@ -959,8 +960,9 @@ def _sweep_plan(cb: CompiledBundle, grid, plan: ExecPlan | None,
                     for f in MATRIX_FIELDS}
             for sl in _chunk_slices(S, chunk):
                 part = run(cb, v._slice(sl), plan)
-                for f in MATRIX_FIELDS:
-                    mats[f][sl] = np.asarray(part[f], dtype=np.float64)
+                with span("repro.price.fetch"):
+                    for f in MATRIX_FIELDS:
+                        mats[f][sl] = np.asarray(part[f], dtype=np.float64)
 
     return SweepResult(grid=grid, compiled=cb, plan=plan, **mats)
 
@@ -1002,13 +1004,14 @@ def _finalize(part: dict, s: int, c: int) -> dict:
     """Normalize one executor output chunk to writable float64 ``(s, c)``
     matrices (kernel outputs are merely *broadcastable* to that shape)."""
     out = {}
-    for f in MATRIX_FIELDS:
-        a = np.asarray(part[f], dtype=np.float64)
-        if a.shape != (s, c):
-            a = np.broadcast_to(a, (s, c))
-        if not a.flags.writeable:
-            a = a.copy()
-        out[f] = np.ascontiguousarray(a)
+    with span("repro.price.fetch"):
+        for f in MATRIX_FIELDS:
+            a = np.asarray(part[f], dtype=np.float64)
+            if a.shape != (s, c):
+                a = np.broadcast_to(a, (s, c))
+            if not a.flags.writeable:
+                a = a.copy()
+            out[f] = np.ascontiguousarray(a)
     return out
 
 
@@ -1208,24 +1211,29 @@ def _sweep_plan_many(bundles, grid, plan: ExecPlan | None, names=None,
             f"backend {plan.backend!r} is a streaming reducer and returns "
             "no per-bundle matrices to split; price each bundle "
             "separately, or pass a matrix backend (see known_backends())")
-    cbs = [b if isinstance(b, CompiledBundle) else compile_bundle(b)
-           for b in bundles]
+    bundles = list(bundles)
     names = tuple(names) if names is not None else ()
-    if names and len(names) != len(cbs):
-        raise ValueError(f"{len(names)} names for {len(cbs)} bundles")
-    if not cbs:
+    if names and len(names) != len(bundles):
+        raise ValueError(f"{len(names)} names for {len(bundles)} bundles")
+    if not bundles:
         return MultiSweepResult(grid=grid, results=(), names=names)
 
-    super_cb = concat_bundles(cbs)
+    calls = sum(b.n_calls if isinstance(b, CompiledBundle)
+                else len(b.call_sites) for b in bundles)
+    with span("repro.price.pack", calls=calls):
+        cbs = [b if isinstance(b, CompiledBundle) else compile_bundle(b)
+               for b in bundles]
+        super_cb = concat_bundles(cbs)
     sup = _sweep_plan(super_cb, grid, plan, mpi_transfer, free_transfer)
     results, lo = [], 0
-    for cb in cbs:
-        hi = lo + cb.n_calls
-        mats = {f: np.ascontiguousarray(getattr(sup, f)[:, lo:hi])
-                for f in MATRIX_FIELDS}
-        results.append(SweepResult(grid=grid, compiled=cb, plan=sup.plan,
-                                   **mats))
-        lo = hi
+    with span("repro.price.split"):
+        for cb in cbs:
+            hi = lo + cb.n_calls
+            mats = {f: np.ascontiguousarray(getattr(sup, f)[:, lo:hi])
+                    for f in MATRIX_FIELDS}
+            results.append(SweepResult(grid=grid, compiled=cb,
+                                       plan=sup.plan, **mats))
+            lo = hi
     return MultiSweepResult(grid=grid, results=tuple(results), names=names)
 
 

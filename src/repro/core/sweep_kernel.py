@@ -50,6 +50,7 @@ import numpy as np
 from .access import (BracketTerms, category_bracket, combine_categories,
                      unpack_blend)
 from .characterization import ALL_CATEGORIES, Characterization
+from .spans import span
 from .transfer import SiteTraffic
 
 #: The ``(n_scenarios, n_calls)`` component matrices a sweep produces, in
@@ -290,23 +291,27 @@ def _jitted_price(cb, key, make_run):
     it's a frozen dataclass), so the jitted executables and the closed-over
     arrays die with the bundle instead of accumulating in a module-level
     registry for the process lifetime.
+
+    Returns ``(fn, miss)``: ``miss`` is True when this call built a new
+    ``jax.jit`` (its first call then traces and lowers again).
     """
     cache = getattr(cb, "_jit_cache", None)
     if cache is None:
         cache = {}
         object.__setattr__(cb, "_jit_cache", cache)
     fn = cache.get(key)
-    if fn is None:
-        jax, _ = _ensure_jax()
-        fn = jax.jit(make_run())
-        cache[key] = fn
-    return fn
+    if fn is not None:
+        return fn, False
+    jax, _ = _ensure_jax()
+    fn = cache[key] = jax.jit(make_run())
+    return fn, True
 
 
 def _grid_jit(cb, vmap_scenarios: bool = False, x64: bool = True):
     """The cached jitted executable behind :func:`price_grid_jax` (its
-    one argument is the view) — split out so ``repro.analysis.ircheck``
-    can trace/lower exactly what production runs without executing it."""
+    one argument is the view) and whether it was built now — split out so
+    ``repro.analysis.ircheck`` can trace/lower exactly what production
+    runs without executing it."""
     jax, jnp = _ensure_jax()
 
     def make_run():
@@ -343,10 +348,19 @@ def price_grid_jax(cb, view, vmap_scenarios: bool = False,
     over the scenario axis instead of the broadcasted batch formulation —
     same results, and the shape accelerator sharding composes with.
     """
-    fn = _grid_jit(cb, vmap_scenarios, x64)
-    with _precision_scope(x64):
-        out = fn(view)
-    return {k: np.asarray(v, dtype=np.float64) for k, v in out.items()}
+    fn, miss = _grid_jit(cb, vmap_scenarios, x64)
+    return _run_jitted(fn, miss, x64, view)
+
+
+def _run_jitted(fn, miss: bool, x64: bool, *args,
+                dtype=np.float64) -> dict:
+    """Call a bundle's jitted executable on ``args`` and copy its outputs
+    to host arrays of ``dtype`` (``None`` keeps theirs), in the
+    ``repro.price.run`` and ``repro.price.fetch`` spans."""
+    with _precision_scope(x64), span("repro.price.run", jit_miss=int(miss)):
+        out = fn(*args)
+    with span("repro.price.fetch"):
+        return {k: np.asarray(v, dtype=dtype) for k, v in out.items()}
 
 
 def _precision_scope(x64: bool):
@@ -408,10 +422,9 @@ def price_grid_pallas(cb, view, interpret: bool | None = None,
 
         return lambda v: price_grid(cb, v, jnp, bracket_terms=bracket_terms)
 
-    fn = _jitted_price(cb, ("pallas", bool(interpret), bool(x64)), make_run)
-    with _precision_scope(x64):
-        out = fn(view)
-    return {k: np.asarray(v, dtype=np.float64) for k, v in out.items()}
+    fn, miss = _jitted_price(cb, ("pallas", bool(interpret), bool(x64)),
+                             make_run)
+    return _run_jitted(fn, miss, x64, view)
 
 
 # --------------------------------------------------------------------------
@@ -433,9 +446,9 @@ DIST_CHUNK_DEFAULT = 65536
 def _topk_chunk_plan(cb, view, valid, idx, k, n_devices: int = 1,
                      x64: bool = True):
     """Validate one chunk's shard geometry and build ``(jitted fn, flat
-    args)`` — the executable :func:`price_topk_chunk` runs (``fn(*flat)``)
-    and ``repro.analysis.ircheck`` traces/lowers for the collective and
-    liveness passes without executing."""
+    args, jit miss)`` — the executable :func:`price_topk_chunk` runs
+    (``fn(*flat)``) and ``repro.analysis.ircheck`` traces/lowers for the
+    collective and liveness passes without executing."""
     jax, jnp = _ensure_jax()
     from jax.sharding import PartitionSpec as P
 
@@ -506,8 +519,8 @@ def _topk_chunk_plan(cb, view, valid, idx, k, n_devices: int = 1,
         return shard_map(shard_fn, mesh=mesh, in_specs=in_specs,
                          out_specs=P("scenarios"))
 
-    fn = _jitted_price(cb, key, make_run)
-    return fn, (valid, idx) + tuple(leaves)
+    fn, miss = _jitted_price(cb, key, make_run)
+    return fn, (valid, idx) + tuple(leaves), miss
 
 
 def price_topk_chunk(cb, view, valid, idx, k, n_devices: int = 1,
@@ -545,11 +558,9 @@ def price_topk_chunk(cb, view, valid, idx, k, n_devices: int = 1,
       * ``n_beneficial`` / ``gain_sum`` — ``(n_dev, n_calls)`` per-call
         beneficial-scenario counts and summed gains over valid rows.
     """
-    fn, flat = _topk_chunk_plan(cb, view, valid, idx, k,
-                                n_devices=n_devices, x64=x64)
-    with _precision_scope(x64):
-        out = fn(*flat)
-    return {name: np.asarray(val) for name, val in out.items()}
+    fn, flat, miss = _topk_chunk_plan(cb, view, valid, idx, k,
+                                      n_devices=n_devices, x64=x64)
+    return _run_jitted(fn, miss, x64, *flat, dtype=None)
 
 
 # --------------------------------------------------------------------------
@@ -588,7 +599,7 @@ def _ircheck_grid_spec():
     grid = ParamGrid.product(ModelParams.multinode(),
                              cxl_lat_ns=[300.0, 400.0, 500.0, 600.0],
                              cxl_atomic_lat_ns=[350.0, 550.0])
-    return EntrySpec(name="sweep.price_grid_jax", fn=_grid_jit(cb),
+    return EntrySpec(name="sweep.price_grid_jax", fn=_grid_jit(cb)[0],
                      args=(_scenario_view(grid),), x64=True,
                      src=src_for(price_grid_jax))
 
@@ -606,8 +617,8 @@ def _ircheck_topk_spec():
     view = _scenario_view(grid)
     valid = np.ones(S, dtype=bool)
     idx = np.arange(S, dtype=np.int64)
-    fn, flat = _topk_chunk_plan(cb, view, valid, idx, k, n_devices=n_dev,
-                                x64=True)
+    fn, flat, _ = _topk_chunk_plan(cb, view, valid, idx, k,
+                                   n_devices=n_dev, x64=True)
     return EntrySpec(name="sweep.price_topk_chunk", fn=fn, args=flat,
                      x64=True, min_devices=n_dev,
                      mesh_axes={"scenarios": n_dev},

@@ -239,8 +239,9 @@ class PagedContinuousEngine(ContinuousEngine):
             caches_b = []
             for i, spec in enumerate(self._pattern):
                 if spec.mixer == "attn":
-                    g = self._gather_slot([pools[i]], table_s,
-                                          self.max_len)[0]
+                    with jax.named_scope("kv_gather"):
+                        g = self._gather_slot([pools[i]], table_s,
+                                              self.max_len)[0]
                     caches_b.append(jax.tree.map(lambda x: x[:, None], g))
                 elif spec.mixer == "mamba":
                     caches_b.append(jax.tree.map(lambda x: x[:, None],
@@ -271,14 +272,15 @@ class PagedContinuousEngine(ContinuousEngine):
         blk = jnp.take_along_axis(tables, (pos // bs)[:, None], axis=1)[:, 0]
         off = pos % bs
         new_pools = []
-        for pl, row in zip(pools, rows):
-            if pl is None:
-                new_pools.append(None)
-            else:
-                # row leaves: (nb, n_slots, H, D); inactive slots write
-                # their (null) table[0] block — harmless by construction
-                new_pools.append(jax.tree.map(
-                    lambda P, r: P.at[:, blk, off].set(r), pl, row))
+        with jax.named_scope("kv_scatter"):
+            for pl, row in zip(pools, rows):
+                if pl is None:
+                    new_pools.append(None)
+                else:
+                    # row leaves: (nb, n_slots, H, D); inactive slots write
+                    # their (null) table[0] block — harmless by construction
+                    new_pools.append(jax.tree.map(
+                        lambda P, r: P.at[:, blk, off].set(r), pl, row))
         return logits, new_pools, new_dense
 
     def _prefill_chunk_step(self, params, pools, table_s, tok, pos):
@@ -425,9 +427,7 @@ class PagedContinuousEngine(ContinuousEngine):
             self.params, self._pools, self._dense,
             jnp.asarray(self._tables), jnp.asarray(self._tokens),
             jnp.asarray(self._pos))
-        key = jax.random.fold_in(self._key,
-                                 0x80000000 + self.stats.decode_steps)
-        return np.asarray(self._sample(logits, key))[:, 0]
+        return logits
 
     def _retire(self, slot: int) -> None:
         req = self._slot_req[slot]

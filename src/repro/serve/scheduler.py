@@ -14,8 +14,13 @@ prompt/output lengths — the orchestration this module owns:
     inert, and decode overwrites each stale cache row before attending it);
   * eos / length retirement frees a slot for the next queued request the
     moment a sequence finishes;
-  * a host-side FIFO request queue plus occupancy / tok-s telemetry
-    (``ServeStats``).
+  * a host-side FIFO request queue plus occupancy telemetry
+    (``ServeStats``);
+  * profiler spans of each scheduler tick (``repro.serve.step``) and its
+    parts — ``repro.serve.admit`` per admission, ``repro.serve.decode``
+    (host work up to the decode dispatch), ``repro.serve.sync`` (the
+    sampled tokens' wait and copy) and ``repro.serve.emit`` (emit, retire,
+    release).  Their stats are host integers only, so tracing adds no sync.
 
 The compiled steps of a deployment (every prefill bucket + the decode
 step) are exactly what the batched advisor prices in one call:
@@ -51,7 +56,7 @@ class Request:
 
 @dataclass
 class ServeStats:
-    """Occupancy / throughput telemetry for one ``run``.
+    """Occupancy telemetry of everything the engine has run.
 
     ``prefills_by_bucket`` counts admissions per compiled prefill step
     (keyed like ``compiled_steps()``: ``"prefill@L"`` for the bucketed
@@ -66,12 +71,9 @@ class ServeStats:
     n_slots: int
     decode_steps: int = 0        # jitted (n_slots, max_len) steps executed
     slot_steps: int = 0          # Σ active slots over those steps
-    idle_steps: int = 0          # scheduler ticks with nothing decodable
     prefills: int = 0
-    prefill_tokens: int = 0      # real (unpadded) prompt tokens prefilled
     generated_tokens: int = 0
     completed: int = 0
-    wall_s: float = 0.0
     prefills_by_bucket: dict = field(default_factory=dict)
     kv_bytes_peak: int = 0       # paged: peak allocated pool bytes
     kv_bytes_dense: int = 0      # dense-equivalent n_slots * max_len bytes
@@ -83,20 +85,9 @@ class ServeStats:
         return self.slot_steps / max(1, self.decode_steps * self.n_slots)
 
     @property
-    def tok_s(self) -> float:
-        return self.generated_tokens / max(self.wall_s, 1e-9)
-
-    def as_dict(self) -> dict:
-        return {"n_slots": self.n_slots, "decode_steps": self.decode_steps,
-                "slot_steps": self.slot_steps, "idle_steps": self.idle_steps,
-                "prefills": self.prefills,
-                "prefill_tokens": self.prefill_tokens,
-                "generated_tokens": self.generated_tokens,
-                "completed": self.completed, "wall_s": self.wall_s,
-                "occupancy": self.occupancy, "tok_s": self.tok_s,
-                "prefills_by_bucket": dict(self.prefills_by_bucket),
-                "kv_bytes_peak": self.kv_bytes_peak,
-                "kv_bytes_dense": self.kv_bytes_dense}
+    def prefill_calls(self) -> int:
+        """Prefill programs dispatched (chunks on the paged path)."""
+        return sum(self.prefills_by_bucket.values())
 
 
 def _next_pow2(n: int) -> int:
@@ -188,9 +179,12 @@ class ContinuousEngine:
         self._outputs: dict = {}
         self._next_rid = 0
         self.stats = ServeStats(n_slots=self.n_slots)
-        #: rid -> {"visible": wall_s, "first": wall_s, "done": wall_s} —
-        #: the raw per-request timestamps the load-generator report turns
-        #: into TTFT / completion-latency percentiles (serve.loadgen)
+        #: rid -> {"queued", "visible", "first", "done"}: perf_counter
+        #: stamps at submission, when the request became visible to the
+        #: scheduler (``run``'s arrival step, or its admission when driven
+        #: through ``step``), its first token and its retirement — what the
+        #: load-generator report turns into TTFT / completion-latency
+        #: percentiles (serve.loadgen)
         self.req_times: dict = {}
         self._key = jax.random.PRNGKey(self.seed)
 
@@ -211,13 +205,14 @@ class ContinuousEngine:
         self._validate_capacity(req)
         self._next_rid += 1
         self._order.append(req.rid)
+        now = time.perf_counter()
         if req.max_new_tokens <= 0:       # nothing to generate: done now
             self._outputs[req.rid] = np.zeros(0, dtype=np.int32)
-            now = time.perf_counter()
-            self.req_times[req.rid] = {"visible": now, "first": now,
-                                       "done": now}
+            self.req_times[req.rid] = {"queued": now, "visible": now,
+                                       "first": now, "done": now}
             self.stats.completed += 1
         else:
+            self.req_times[req.rid] = {"queued": now}
             self._queue.append(req)
         return req.rid
 
@@ -253,9 +248,17 @@ class ContinuousEngine:
 
     def _admit(self, req: Request, slot: int) -> None:
         S = len(req.tokens)
-        logits = self._prefill_into_slot(req, slot)
-        key = jax.random.fold_in(self._key, req.rid)
-        tok = int(np.asarray(self._sample(logits, key))[0, 0])
+        t = self.req_times[req.rid]
+        start = time.perf_counter()
+        t.setdefault("visible", start)
+        calls = self.stats.prefill_calls
+        with jax.profiler.TraceAnnotation(
+                "repro.serve.admit", prompt=S,
+                waited_us=int((start - t["queued"]) * 1e6)) as span:
+            logits = self._prefill_into_slot(req, slot)
+            key = jax.random.fold_in(self._key, req.rid)
+            tok = int(np.asarray(self._sample(logits, key))[0, 0])
+            span.set_metadata(chunks=self.stats.prefill_calls - calls)
         self._slot_req[slot] = req
         self._pos[slot] = S
         self._tokens[slot, 0] = tok
@@ -263,9 +266,6 @@ class ContinuousEngine:
         self._emitted[slot] = 0
         self._outputs[req.rid] = []
         self.stats.prefills += 1
-        self.stats.prefill_tokens += S
-        t = self.req_times.setdefault(req.rid,
-                                      {"visible": time.perf_counter()})
         t["first"] = time.perf_counter()
         self._emit(slot, tok)
 
@@ -295,43 +295,57 @@ class ContinuousEngine:
         return True
 
     def _decode_active(self):
-        """Run the jitted decode step over all slots; returns the (B, 1)
-        sampled host tokens (paged engine overrides: block-table growth +
+        """Dispatch the jitted decode step over all slots; returns its
+        (device) logits (paged engine overrides: block-table growth +
         gather/scatter decode)."""
         logits, self.caches = self._decode(
             self.params, self.caches, jnp.asarray(self._tokens),
             jnp.asarray(self._pos))
-        # decode keys live in the upper uint32 half; prefill keys (folded by
-        # rid) in the lower — disjoint streams from one seed
-        key = jax.random.fold_in(self._key,
-                                 0x80000000 + self.stats.decode_steps)
-        return np.asarray(self._sample(logits, key))[:, 0]
+        return logits
 
     def step(self, now: int = 0) -> bool:
         """One scheduler tick: admit what fits, then decode every active
         slot once.  Returns True if any work (admission or decode) ran."""
-        for slot in range(self.n_slots):
-            if self._slot_req[slot] is not None or not self._queue:
-                continue
-            if self._queue[0].arrival > now:
-                break                      # FIFO: don't jump future arrivals
-            if not self._can_admit(self._queue[0]):
-                break                      # FIFO: wait for blocks to free
-            self._admit(self._queue.pop(0), slot)
-        active = [s for s in range(self.n_slots)
-                  if self._slot_req[s] is not None]
-        if not active:
-            self.stats.idle_steps += 1
-            return False
-        sampled = self._decode_active()
-        self.stats.decode_steps += 1
-        self.stats.slot_steps += len(active)
-        for slot in active:
-            self._pos[slot] += 1
-            tok = int(sampled[slot])
-            self._tokens[slot, 0] = tok
-            self._emit(slot, tok)
-        return True
+        with jax.profiler.TraceAnnotation("repro.serve.step",
+                                          queued=len(self._queue)) as span:
+            calls = self.stats.prefill_calls
+            admitted = 0
+            for slot in range(self.n_slots):
+                if self._slot_req[slot] is not None or not self._queue:
+                    continue
+                if self._queue[0].arrival > now:
+                    break                  # FIFO: don't jump future arrivals
+                if not self._can_admit(self._queue[0]):
+                    break                  # FIFO: wait for blocks to free
+                self._admit(self._queue.pop(0), slot)
+                admitted += 1
+            active = [s for s in range(self.n_slots)
+                      if self._slot_req[s] is not None]
+            # live: the positions the decode attends over, summed
+            span.set_metadata(
+                admitted=admitted, chunks=self.stats.prefill_calls - calls,
+                active=len(active),
+                live=int(self._pos[active].sum()) + len(active))
+            if not active:
+                return False
+            with jax.profiler.TraceAnnotation("repro.serve.decode"):
+                logits = self._decode_active()
+            with jax.profiler.TraceAnnotation("repro.serve.sync"):
+                # decode keys live in the upper uint32 half; prefill keys
+                # (folded by rid) in the lower — disjoint streams from one
+                # seed
+                key = jax.random.fold_in(
+                    self._key, 0x80000000 + self.stats.decode_steps)
+                sampled = np.asarray(self._sample(logits, key))[:, 0]
+            self.stats.decode_steps += 1
+            self.stats.slot_steps += len(active)
+            with jax.profiler.TraceAnnotation("repro.serve.emit"):
+                for slot in active:
+                    self._pos[slot] += 1
+                    tok = int(sampled[slot])
+                    self._tokens[slot, 0] = tok
+                    self._emit(slot, tok)
+            return True
 
     def run(self, requests=None) -> list:
         """Drain the queue (plus ``requests``: ``(tokens, max_new)`` or
@@ -340,17 +354,15 @@ class ContinuousEngine:
         for r in requests or ():
             self.submit(*r)
         self._queue.sort(key=lambda r: (r.arrival, r.rid))
-        t0 = time.perf_counter()
         now = 0
         while self._queue or any(r is not None for r in self._slot_req):
             wall = time.perf_counter()
             for r in self._queue:
                 if r.arrival > now:
                     break                  # queue is arrival-sorted
-                self.req_times.setdefault(r.rid, {"visible": wall})
+                self.req_times[r.rid].setdefault("visible", wall)
             self.step(now)
             now += 1
-        self.stats.wall_s += time.perf_counter() - t0
         out = [self._outputs[rid] for rid in self._order]
         self._order = []
         self._outputs = {}
