@@ -48,9 +48,12 @@ def register_backend(name: str, fn: Callable, *, streaming: bool = False,
 
     A MATRIX backend (the default) is
     ``fn(cb, view, plan) -> {field: matrix}`` for every ``MATRIX_FIELDS``
-    key, each broadcastable to ``(n_scenarios, n_calls)``; the execution
-    core wraps it with scenario-axis chunking and builds a full
-    ``SweepResult``.
+    key, each broadcastable to ``(n_scenarios, n_calls)``, as a host
+    (numpy) or a device (jax) array; the execution core wraps it with
+    scenario-axis chunking and builds a full ``SweepResult``.  The core,
+    not the backend, copies device outputs to the host and widens them to
+    float64, so a backend returns its results where and as it computed
+    them.
 
     A STREAMING backend (``streaming=True``) owns its whole execution:
     ``fn(cb, scenarios, plan, mpi_transfer, free_transfer)`` receives the

@@ -683,6 +683,11 @@ class SweepResult:
       2. where to invest first    -> :meth:`ranked_call_indices`
       3. limited CXL capacity     -> :meth:`prioritize_for_capacity`
     plus the application-level projection (:meth:`predicted_speedup`).
+
+    The four matrices are writable float64 ``(n_scenarios, n_calls)``
+    arrays.  An unchunked sweep hands out the transposed, F-ordered view
+    of one call-major block per matrix; a chunked one C-ordered arrays.
+    No two results of one sweep share memory.
     """
 
     grid: ParamGrid
@@ -939,32 +944,81 @@ def _sweep_plan(cb: CompiledBundle, grid, plan: ExecPlan | None,
     (canonically :class:`TopKSweepResult`).
     """
     plan = (plan if plan is not None else ExecPlan()).resolved()
-    run = resolve_backend(plan.backend)
     if is_streaming(plan.backend):
-        return run(cb, grid, plan, mpi_transfer, free_transfer)
-    S, C = len(grid), cb.n_calls
-
-    if S == 0 or C == 0:
-        mats = {f: np.zeros((S, C)) for f in MATRIX_FIELDS}
-    else:
-        v = _scenario_view(grid, mpi_transfer, free_transfer)
-        chunk = plan.chunk_scenarios
-        if chunk is None or chunk >= S:
-            mats = _finalize(run(cb, v, plan), S, C)
-        else:
-            # preallocate the output matrices ONCE and write each chunk's
-            # rows in place — concatenating per-chunk copies cost ~2.5x
-            # at small chunk sizes (assignment also broadcasts (s, 1)
-            # kernel outputs, so results stay bit-identical)
-            mats = {f: np.empty((S, C), dtype=np.float64)
-                    for f in MATRIX_FIELDS}
-            for sl in _chunk_slices(S, chunk):
-                part = run(cb, v._slice(sl), plan)
-                with span("repro.price.fetch"):
-                    for f in MATRIX_FIELDS:
-                        mats[f][sl] = np.asarray(part[f], dtype=np.float64)
-
+        return resolve_backend(plan.backend)(cb, grid, plan, mpi_transfer,
+                                             free_transfer)
+    (mats,) = _price_matrices(cb, grid, plan, [cb.n_calls],
+                              mpi_transfer, free_transfer)
     return SweepResult(grid=grid, compiled=cb, plan=plan, **mats)
+
+
+def _price_matrices(cb: CompiledBundle, grid, plan: ExecPlan, widths,
+                    mpi_transfer=None, free_transfer=None) -> list:
+    """Price ``cb`` under ``grid`` with ``plan``'s (resolved) matrix
+    backend and return one ``{field: (S, w) float64 matrix}`` per entry of
+    ``widths``, the call counts of consecutive column ranges (one per
+    bundle of a super-bundle; they sum to ``cb.n_calls``).
+
+    Unchunked, each range's matrix is the transposed view of a fresh
+    call-major ``(w, S)`` block (F-ordered); chunked, it is preallocated
+    C-ordered and each chunk writes its rows.  Either way every matrix is
+    writable and shares memory with no other.
+    """
+    run = resolve_backend(plan.backend)
+    S = len(grid)
+    bounds = list(zip(np.cumsum([0, *widths[:-1]]), np.cumsum(widths)))
+    if S == 0 or cb.n_calls == 0:
+        return [{f: np.zeros((S, hi - lo)) for f in MATRIX_FIELDS}
+                for lo, hi in bounds]
+    v = _scenario_view(grid, mpi_transfer, free_transfer)
+    chunk = plan.chunk_scenarios
+    if chunk is None:
+        blocks = [{f: np.empty((hi - lo, S)) for f in MATRIX_FIELDS}
+                  for lo, hi in bounds]
+        _assemble(run(cb, v, plan), bounds, blocks)
+        return [{f: b.T for f, b in blk.items()} for blk in blocks]
+    # a plan that chunks keeps C-ordered matrices, even in one chunk:
+    # preallocate them ONCE and write each chunk's rows in place —
+    # concatenating per-chunk copies cost ~2.5x at small chunk sizes
+    mats = [{f: np.empty((S, hi - lo)) for f in MATRIX_FIELDS}
+            for lo, hi in bounds]
+    for sl in _chunk_slices(S, chunk):
+        _assemble(run(cb, v._slice(sl), plan), bounds,
+                  [{f: m[sl].T for f, m in ms.items()} for ms in mats])
+    return mats
+
+
+def _call_major(a):
+    """A matrix broadcastable to ``(s, c)`` as a 2-D ``(c | 1, s | 1)``
+    transpose: a view of a host array, or one op on a device array, whose
+    copy to the host it starts."""
+    device = hasattr(a, "copy_to_host_async")
+    a = a if device else np.asarray(a)
+    a = a.reshape((1,) * (2 - a.ndim) + a.shape).T
+    if device:
+        a.copy_to_host_async()
+    return a
+
+
+def _assemble(out: dict, bounds, blocks: list) -> None:
+    """Write one executor output into float64 call-major blocks: for each
+    field and each call range ``[lo, hi)`` of ``bounds``, the field's rows
+    ``lo:hi`` of its call-major form go into ``blocks[i][field]``, a
+    writable ``(hi - lo, s)`` array, in ONE assignment that widens to
+    float64 and broadcasts a field with no scenario axis.
+
+    Executor outputs are merely broadcastable to ``(s, n_calls)``; device
+    outputs are transposed on the device, and every copy to the host is
+    started before the first is waited on.  The ``repro.price.fetch``
+    span's ``host_mb`` is the float64 megabytes written.
+    """
+    host_mb = sum(b.size for blk in blocks for b in blk.values()) * 8 / 1e6
+    with span("repro.price.fetch", host_mb=host_mb):
+        cm = {f: _call_major(out[f]) for f in MATRIX_FIELDS}
+        for f, a in cm.items():
+            a = np.asarray(a)
+            for (lo, hi), blk in zip(bounds, blocks):
+                blk[f][...] = a if a.shape[0] == 1 else a[lo:hi]
 
 
 def sweep_run(bundle, grid: ParamGrid, mpi_transfer=None, free_transfer=None,
@@ -998,21 +1052,6 @@ def sweep_run(bundle, grid: ParamGrid, mpi_transfer=None, free_transfer=None,
                        pallas_interpret=pallas_interpret)
     cb = bundle if isinstance(bundle, CompiledBundle) else compile_bundle(bundle)
     return _sweep_plan(cb, grid, plan, mpi_transfer, free_transfer)
-
-
-def _finalize(part: dict, s: int, c: int) -> dict:
-    """Normalize one executor output chunk to writable float64 ``(s, c)``
-    matrices (kernel outputs are merely *broadcastable* to that shape)."""
-    out = {}
-    with span("repro.price.fetch"):
-        for f in MATRIX_FIELDS:
-            a = np.asarray(part[f], dtype=np.float64)
-            if a.shape != (s, c):
-                a = np.broadcast_to(a, (s, c))
-            if not a.flags.writeable:
-                a = a.copy()
-            out[f] = np.ascontiguousarray(a)
-    return out
 
 
 # --------------------------------------------------------------------------
@@ -1105,9 +1144,13 @@ class MultiSweepResult:
     """Per-bundle ``SweepResult``s priced in ONE batched evaluation.
 
     ``sweep_run_many`` packs every bundle into a super-bundle, prices the
-    whole thing under the grid, then splits the component matrices back
-    per bundle — so ``result[i]`` carries exactly what ``sweep_run(bundle_i,
-    grid)`` would (same backend), while the kernel ran once.
+    whole thing under the grid, then writes each bundle's columns into
+    matrices of its own — so ``result[i]`` carries exactly what
+    ``sweep_run(bundle_i, grid)`` would (same backend), while the kernel
+    ran once.  Each bundle's matrices are writable float64 ``(S, c_b)``,
+    possibly F-ordered views of one call-major block per matrix, and no
+    two bundles share memory: writing into one leaves the others as they
+    were.
 
     ``names`` labels the bundles (e.g. ``"prefill@64"`` / ``"decode"`` for
     a serving deployment's compiled steps).
@@ -1205,7 +1248,11 @@ def _sweep_plan_many(bundles, grid, plan: ExecPlan | None, names=None,
                      ) -> MultiSweepResult:
     """Multi-bundle execution core: pack every bundle into one
     offset-segment-id super-bundle (:func:`concat_bundles`), price it with
-    ONE backend invocation, split the matrices back per bundle."""
+    ONE backend invocation, and write each bundle's columns straight into
+    its own matrices: writable float64 ``(S, c_b)``, unchunked the
+    F-ordered views of one call-major ``(c_b, S)`` block per field, in
+    one float64 pass over the executor's output.  Bundles share no
+    memory."""
     if plan is not None and is_streaming(plan.backend):
         raise ValueError(
             f"backend {plan.backend!r} is a streaming reducer and returns "
@@ -1224,17 +1271,14 @@ def _sweep_plan_many(bundles, grid, plan: ExecPlan | None, names=None,
         cbs = [b if isinstance(b, CompiledBundle) else compile_bundle(b)
                for b in bundles]
         super_cb = concat_bundles(cbs)
-    sup = _sweep_plan(super_cb, grid, plan, mpi_transfer, free_transfer)
-    results, lo = [], 0
+    plan = (plan if plan is not None else ExecPlan()).resolved()
+    parts = _price_matrices(super_cb, grid, plan,
+                            [cb.n_calls for cb in cbs],
+                            mpi_transfer, free_transfer)
     with span("repro.price.split"):
-        for cb in cbs:
-            hi = lo + cb.n_calls
-            mats = {f: np.ascontiguousarray(getattr(sup, f)[:, lo:hi])
-                    for f in MATRIX_FIELDS}
-            results.append(SweepResult(grid=grid, compiled=cb,
-                                       plan=sup.plan, **mats))
-            lo = hi
-    return MultiSweepResult(grid=grid, results=tuple(results), names=names)
+        results = tuple(SweepResult(grid=grid, compiled=cb, plan=plan, **m)
+                        for cb, m in zip(cbs, parts))
+    return MultiSweepResult(grid=grid, results=results, names=names)
 
 
 def sweep_run_many(bundles, grid: ParamGrid, names=None, mpi_transfer=None,

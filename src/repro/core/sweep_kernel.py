@@ -347,20 +347,20 @@ def price_grid_jax(cb, view, vmap_scenarios: bool = False,
     ``vmap_scenarios=True`` runs ``jax.vmap`` of the per-scenario kernel
     over the scenario axis instead of the broadcasted batch formulation —
     same results, and the shape accelerator sharding composes with.
+
+    Returns the matrices as device arrays; the sweep core copies them to
+    the host.
     """
     fn, miss = _grid_jit(cb, vmap_scenarios, x64)
     return _run_jitted(fn, miss, x64, view)
 
 
-def _run_jitted(fn, miss: bool, x64: bool, *args,
-                dtype=np.float64) -> dict:
-    """Call a bundle's jitted executable on ``args`` and copy its outputs
-    to host arrays of ``dtype`` (``None`` keeps theirs), in the
-    ``repro.price.run`` and ``repro.price.fetch`` spans."""
+def _run_jitted(fn, miss: bool, x64: bool, *args) -> dict:
+    """Call a bundle's jitted executable on ``args`` in the
+    ``repro.price.run`` span; its outputs stay on the device (the sweep
+    core fetches matrices, :func:`price_topk_chunk` its reductions)."""
     with _precision_scope(x64), span("repro.price.run", jit_miss=int(miss)):
-        out = fn(*args)
-    with span("repro.price.fetch"):
-        return {k: np.asarray(v, dtype=dtype) for k, v in out.items()}
+        return fn(*args)
 
 
 def _precision_scope(x64: bool):
@@ -406,7 +406,8 @@ def price_grid_pallas(cb, view, interpret: bool | None = None,
 
     ``interpret`` / ``x64`` default to the platform (:func:`pallas_modes`):
     the compiled kernel in float32 on TPU, the interpreter in float64
-    elsewhere (the CPU validation mode).
+    elsewhere (the CPU validation mode).  Returns device arrays, as
+    :func:`price_grid_jax` does.
     """
     interpret, x64 = pallas_modes(interpret, x64)
     _, jnp = _ensure_jax()
@@ -560,7 +561,9 @@ def price_topk_chunk(cb, view, valid, idx, k, n_devices: int = 1,
     """
     fn, flat, miss = _topk_chunk_plan(cb, view, valid, idx, k,
                                       n_devices=n_devices, x64=x64)
-    return _run_jitted(fn, miss, x64, *flat, dtype=None)
+    out = _run_jitted(fn, miss, x64, *flat)
+    with span("repro.price.fetch"):
+        return {name: np.asarray(v) for name, v in out.items()}
 
 
 # --------------------------------------------------------------------------

@@ -82,17 +82,19 @@ def recorded(tmp_path_factory):
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
     opts.host_tracer_level = 1
-    marks = {}
+    marks, results = {}, {}
     jax.profiler.start_trace(str(out), profiler_options=opts)
     try:
         for section, run in (
                 ("list", lambda: price(bundles, grid, plan="jax")),
                 ("single", lambda: price(cb, grid, plan="jax")),
                 ("stream", lambda: price(
-                    cb, design, plan="distributed:topk=4,chunk=16"))):
-            for _ in range(1 if section == "stream" else 2):
+                    cb, design, plan="distributed:topk=4,chunk=16")),
+                ("chunked", lambda: price(bundles, grid,
+                                          plan="jax:chunk=4"))):
+            for _ in range(2 if section in ("list", "single") else 1):
                 with jax.profiler.TraceAnnotation("test." + section):
-                    run()
+                    results[section] = run()
         with jax.profiler.TraceAnnotation("test.serve"):
             rids = [eng.submit(p, 3) for p in prompts]
             for _ in range(4):
@@ -110,7 +112,7 @@ def recorded(tmp_path_factory):
     by = {k: [s for s in spans if any(s.inside(m) for m in v)]
           for k, v in marks.items()}
     return {"by": by, "seen": seen, "engine": eng, "rids": rids,
-            "grid": grid}
+            "grid": grid, "results": results}
 
 
 def _named(spans, name):
@@ -127,11 +129,34 @@ def test_price_list_spans_nest_and_miss_every_call(recorded):
         inner = [s for s in spans if s is not top and s.inside(top)]
         assert [s.name for s in inner] == [
             "repro.price.pack", "repro.price.run", "repro.price.fetch",
-            "repro.price.fetch", "repro.price.split"]
+            "repro.price.split"]
         assert inner[0].stats == {"calls": 5}
     # each call packs a new super-bundle, so its jit is built again
     assert [s.stats["jit_miss"] for s in _named(spans, "repro.price.run")] \
         == [1, 1]
+
+
+def test_fetch_counts_the_float64_written_once(recorded):
+    """One call's assembly writes each of the four float64 matrices of
+    every bundle once: ``4 * S * C * 8`` bytes, C the calls of all."""
+    spans, S = recorded["by"]["list"], len(recorded["grid"])
+    fetches = _named(spans, "repro.price.fetch")
+    assert [f.stats["host_mb"] for f in fetches] == [4 * S * 5 * 8 / 1e6] * 2
+
+
+def test_chunked_fetch_writes_rows_into_c_ordered_matrices(recorded):
+    """A chunking plan writes each chunk's rows into C-ordered matrices:
+    one fetch per chunk, counting that chunk's float64 only."""
+    spans = recorded["by"]["chunked"]
+    S = len(recorded["grid"])
+    assert [f.stats["host_mb"] for f in _named(spans, "repro.price.fetch")] \
+        == [4 * 4 * 5 * 8 / 1e6, 4 * (S - 4) * 5 * 8 / 1e6]
+    for r in recorded["results"]["chunked"]:
+        for f in ("t_transfer_mpi_ns", "t_transfer_cxl_ns",
+                  "t_access_mpi_ns", "t_access_cxl_ns"):
+            m = getattr(r, f)
+            assert m.flags.writeable and m.flags.c_contiguous
+            assert m.shape == (S, r.compiled.n_calls)
 
 
 def test_price_single_bundle_hits_its_jit_cache(recorded):

@@ -2,13 +2,18 @@
 ``sweep_run`` on every backend (numpy / jax / pallas-interpret), the
 empty/single/zero-call edge cases, deployment-level aggregates, and the
 ``CommAdvisor.sweep_text_many`` flow."""
+import itertools
+
 import numpy as np
 import pytest
 
-from repro.core import (CommAdvisor, CommRecord, CounterSet, DataSource,
-                        LoadSample, ModelParams, MultiSweepResult, ParamGrid,
-                        TraceBundle, compile_bundle, concat_bundles,
-                        sweep_run, sweep_run_many)
+from repro.core import (ArraySet, CommAdvisor, CommRecord, CounterSet,
+                        DataSource, ExecPlan, HockneyTransfer, LoadSample,
+                        ModelParams, MultiSweepResult, ParamGrid, TraceBundle,
+                        compile_bundle, concat_bundles, price, sweep_run,
+                        sweep_run_many)
+from repro.core.execplan import resolve_backend
+from repro.core.sweep import _scenario_view
 from repro.core.sweep_kernel import MATRIX_FIELDS
 
 RTOL = 1e-9           # acceptance bound: super-bundle == per-bundle runs
@@ -55,6 +60,19 @@ def grid():
                              cxl_atomic_lat_ns=[350.0, 653.0])
 
 
+@pytest.fixture(scope="module")
+def latencies():
+    """Only the CXL latencies vary; every other field is one ``(1, 1)``
+    value, so ``t_access_mpi_ns`` comes back ``(1, n_calls)``."""
+    rng = np.random.default_rng(7)
+    n = 11
+    return ArraySet(base=ModelParams.multinode(), n=n,
+                    columns={"cxl_lat_ns": rng.uniform(250.0, 700.0, n),
+                             "cxl_atomic_lat_ns": rng.uniform(300.0, 800.0,
+                                                              n)},
+                    cat={}, ranges={})
+
+
 def _assert_matches(multi, singles, ctx=""):
     assert len(multi) == len(singles)
     for i, (rm, rs) in enumerate(zip(multi, singles)):
@@ -93,6 +111,68 @@ def test_single_bundle_list(bundles, grid, backend):
     _assert_matches(multi, [sweep_run(bundles[0], grid, backend=backend)])
     assert multi.names == ("only",)
     assert multi["only"] is multi[0]
+
+
+def _super_columns(cbs, scenarios, backend, mpi_transfer):
+    """The reference assembly: the super-bundle's raw executor output
+    widened to float64, broadcast to ``(S, C)`` and cut per bundle."""
+    sup = concat_bundles(cbs)
+    out = resolve_backend(backend)(
+        sup, _scenario_view(scenarios, mpi_transfer),
+        ExecPlan(backend=backend).resolved())
+    full = {f: np.broadcast_to(np.asarray(out[f], np.float64),
+                               (len(scenarios), sup.n_calls))
+            for f in MATRIX_FIELDS}
+    ends = np.cumsum([cb.n_calls for cb in cbs])
+    cols = [{f: m[:, hi - cb.n_calls:hi] for f, m in full.items()}
+            for cb, hi in zip(cbs, ends)]
+    return cols, {f: np.shape(out[f]) for f in MATRIX_FIELDS}
+
+
+@pytest.mark.parametrize("backend", BACKENDS)
+@pytest.mark.parametrize("case", ["grid", "latencies", "latencies+hockney"])
+def test_assembly_bit_identical(bundles, grid, latencies, backend, case):
+    """Each bundle's matrices equal, bit for bit, both its own single run
+    and its columns of the super-bundle's executor output — also where a
+    field has no scenario axis (the broadcast branch) or no 2-D shape at
+    all (a scalar-field transfer override)."""
+    scenarios = grid if case == "grid" else latencies
+    mpi = HockneyTransfer(320.0, 9.4) if case.endswith("hockney") else None
+    cbs = [compile_bundle(b) for b in bundles]
+    multi = price(cbs, scenarios, plan=backend, mpi_transfer=mpi)
+    cols, shapes = _super_columns(cbs, scenarios, backend, mpi)
+    if case != "grid":
+        assert shapes["t_access_mpi_ns"][0] == 1
+    if mpi is not None:
+        assert len(shapes["t_transfer_mpi_ns"]) == 1
+    for cb, rm, want in zip(cbs, multi, cols):
+        rs = price(cb, scenarios, plan=backend, mpi_transfer=mpi)
+        for f in MATRIX_FIELDS:
+            np.testing.assert_array_equal(getattr(rm, f), want[f])
+            np.testing.assert_array_equal(getattr(rm, f), getattr(rs, f))
+
+
+@pytest.mark.parametrize("backend", BACKENDS)
+@pytest.mark.parametrize("chunk", [None, 4])
+def test_bundle_matrices_are_their_own(bundles, latencies, backend, chunk):
+    """Every bundle's matrices are writable float64 ``(S, c_b)`` that share
+    memory with no other, so an in-place write to one leaves the rest."""
+    multi = price(bundles, latencies,
+                  plan=ExecPlan(backend=backend, chunk_scenarios=chunk))
+    mats = [getattr(r, f) for r in multi for f in MATRIX_FIELDS]
+    for r in multi:
+        for f in MATRIX_FIELDS:
+            m = getattr(r, f)
+            assert m.dtype == np.float64 and m.flags.writeable
+            assert m.shape == (len(latencies), r.compiled.n_calls)
+            if chunk is not None:
+                assert m.flags.c_contiguous
+    for a, b in itertools.combinations(mats, 2):
+        assert not np.shares_memory(a, b)
+    before = [m.copy() for m in mats]
+    mats[0] *= -1.0
+    for m, old in zip(mats[1:], before[1:]):
+        np.testing.assert_array_equal(m, old)
 
 
 def test_empty_bundle_list(grid):
