@@ -200,7 +200,9 @@ def stack_prefill(stacked, x, cfg: ArchConfig, max_len: int, positions=None,
                 new_caches.append({"k": jnp.pad(k, pad), "v": jnp.pad(v, pad)})
             elif spec.mixer == "mamba":
                 h = layers.rms_norm(x, p["mixer_norm"], cfg.norm_eps)
-                out, state = mamba.mamba_prefill(p["mamba"], h, cfg)
+                out, state = mamba.mamba_chunk(
+                    p["mamba"], h, cfg,
+                    mamba.init_mamba_state(cfg, x.shape[0]))
                 x = x + out
                 new_caches.append(state)
             else:
@@ -227,8 +229,11 @@ def stack_prefill(stacked, x, cfg: ArchConfig, max_len: int, positions=None,
 
 
 def stack_decode(stacked, caches, x, cfg: ArchConfig, pos,
-                 moe_impl: str = "scatter", act_pspec=None):
-    """One-token step through the stack.  x: (B, 1, d); pos: scalar."""
+                 moe_impl: str = "scatter", act_pspec=None, n_valid=None):
+    """A step of ``S`` new tokens through the stack from its caches.
+    x: (B, S, d); pos: scalar index of the first; ``n_valid`` (optional,
+    may be traced): the chunk's real tokens, the rest padding — mamba
+    layers return their state as of the last real token."""
     pattern = layer_pattern(cfg)
 
     def block_body(x, scanned):
@@ -244,7 +249,8 @@ def stack_decode(stacked, caches, x, cfg: ArchConfig, pos,
                 new_caches.append({"k": ck, "v": cv})
             elif spec.mixer == "mamba":
                 h = layers.rms_norm(x, p["mixer_norm"], cfg.norm_eps)
-                out, state = mamba.mamba_decode(p["mamba"], h, cfg, c)
+                out, state = mamba.mamba_chunk(p["mamba"], h, cfg, c,
+                                               n_valid)
                 x = x + out
                 new_caches.append(state)
             else:

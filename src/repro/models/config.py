@@ -31,6 +31,7 @@ class ArchConfig:
     ssm_state: int = 0
     ssm_conv: int = 4
     ssm_expand: int = 2
+    ssm_inner_norm: bool = False   # RMSNorm on dt, B, C after x_proj (Jamba)
 
     # --- hybrid interleave (Jamba: attn every 8th layer, MoE every 2nd) -----
     attn_period: int = 0           # 0 => all layers attend (or none if n_heads=0)
@@ -41,6 +42,8 @@ class ArchConfig:
     # --- misc ----------------------------------------------------------------
     mlp_act: str = "swiglu"        # swiglu | geglu
     qkv_bias: bool = False
+    rope: bool = True              # False: attention has no positional
+                                   # encoding (Jamba)
     rope_theta: float = 1e4
     norm_eps: float = 1e-5
     tie_embeddings: bool = False

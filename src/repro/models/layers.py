@@ -114,8 +114,9 @@ def _project_qkv(p, x, cfg: ArchConfig, positions):
     q = q.reshape(B, S, nq, hd)
     k = k.reshape(B, S, cfg.n_kv_heads, hd)
     v = v.reshape(B, S, cfg.n_kv_heads, hd)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    if cfg.rope:
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k = apply_rope(k, positions, cfg.rope_theta)
     return q, k, v
 
 

@@ -127,14 +127,20 @@ class LanguageModel:
                 x, jnp.broadcast_to(idx, (x.shape[0], 1, x.shape[2])), axis=1)
         return self._head(params, x_last), caches
 
-    def decode_step(self, params, caches, batch, pos):
-        """One new token.  ``batch`` carries the single-position inputs
-        ({"tokens": (B, 1)} or {"frame_embeds": (B, 1, F)}); ``pos`` is the
-        scalar write index into the caches."""
+    def decode_step(self, params, caches, batch, pos, n_valid=None):
+        """New tokens from the caches.  ``batch`` carries the inputs at
+        positions ``pos ..`` ({"tokens": (B, S)} or {"frame_embeds": (B,
+        S, F)}); ``pos`` is the scalar write index of the first.  With
+        ``n_valid`` (may be traced) only the first ``n_valid`` tokens are
+        real: mamba states stop at the last of them, and only its logits
+        ``(B, 1, ...)`` are returned."""
         x = self._embed_inputs(params, batch)
         x, caches = blocks.stack_decode(
             params["stack"], caches, x, self.cfg, pos,
-            moe_impl=self.moe_impl, act_pspec=self.act_pspec)
+            moe_impl=self.moe_impl, act_pspec=self.act_pspec,
+            n_valid=n_valid)
+        if n_valid is not None:
+            x = jax.lax.dynamic_slice_in_dim(x, n_valid - 1, 1, axis=1)
         return self._head(params, x), caches
 
     def init_caches(self, batch_size: int, max_len: int):

@@ -6,10 +6,17 @@ Pure-functional JAX, matching the reference formulation:
 with input-dependent (selective) dt/B/C, depthwise causal conv front-end and
 a SiLU-gated output path.
 
-Training/prefill uses a chunked ``lax.scan`` (checkpointed per chunk so the
-backward pass stores O(L/chunk) states, not O(L)); single-token decode
-carries ``(conv_state, ssm_state)``.  The TPU hot path is the Pallas kernel
-in ``repro.kernels.mamba_scan`` (selected by ``use_kernel``).
+Training uses a chunked ``lax.scan`` (checkpointed per chunk so the
+backward pass stores O(L/chunk) states, not O(L)); the Pallas kernel in
+``repro.kernels.mamba_scan`` replaces it when ``use_kernel`` is set.
+Serving runs :func:`mamba_chunk`, one multi-token step that starts from a
+``MambaState`` (conv tail + recurrent state) and returns the state as of
+the chunk's last real token; prefill and single-token decode are its
+special cases.
+
+Jamba's mixer (``cfg.ssm_inner_norm``) applies RMSNorm to ``dt``, ``B``
+and ``C`` after ``x_proj`` (``dt_layernorm``/``b_layernorm``/
+``c_layernorm`` in the Jamba modeling code).
 """
 from __future__ import annotations
 
@@ -49,7 +56,7 @@ def init_mamba(cfg: ArchConfig, key) -> dict:
     u = jax.random.uniform(keys[5], (di,), jnp.float32)
     dt_init = jnp.exp(u * (math.log(0.1) - math.log(1e-3)) + math.log(1e-3))
     dt_bias = dt_init + jnp.log(-jnp.expm1(-dt_init))  # inverse softplus
-    return {
+    p = {
         "in_proj": jax.random.normal(keys[0], (d, 2 * di), dt) * s,
         "conv_w": jax.random.normal(keys[1], (K, di), dt) * (1.0 / math.sqrt(K)),
         "conv_b": jnp.zeros((di,), dt),
@@ -62,16 +69,31 @@ def init_mamba(cfg: ArchConfig, key) -> dict:
         "out_proj": jax.random.normal(keys[4], (di, d), dt)
         * (1.0 / math.sqrt(di) / math.sqrt(cfg.n_layers)),
     }
+    if cfg.ssm_inner_norm:
+        p["dt_norm"] = jnp.ones((r,), dt)
+        p["b_norm"] = jnp.ones((N,), dt)
+        p["c_norm"] = jnp.ones((N,), dt)
+    return p
 
 
-def _causal_conv(x, w, b):
-    """Depthwise causal conv along time.  x: (B, L, di), w: (K, di)."""
+def _causal_conv(x, w, b, tail=None):
+    """Depthwise causal conv along time.  x: (B, L, di), w: (K, di);
+    ``tail`` (B, K-1, di) holds the inputs before ``x`` (zeros if None)."""
     K = w.shape[0]
-    pad = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0)))
+    if tail is None:
+        pad = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0)))
+    else:
+        pad = jnp.concatenate([tail.astype(x.dtype), x], axis=1)
     out = jnp.zeros_like(x)
     for k in range(K):       # K is 4: unrolled taps beat a conv op on TPU
         out = out + pad[:, k: k + x.shape[1], :] * w[k]
     return out + b
+
+
+def _rms(x, scale, eps):
+    """RMSNorm in float32 (the Jamba inner norms; x is f32 already)."""
+    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) \
+        * scale.astype(jnp.float32)
 
 
 def _ssm_inputs(p, x, cfg: ArchConfig):
@@ -79,6 +101,10 @@ def _ssm_inputs(p, x, cfg: ArchConfig):
     r, N = dt_rank(cfg), cfg.ssm_state
     proj = (x @ p["x_proj"]).astype(jnp.float32)          # (B, L, r + 2N)
     dt_low, Bt, Ct = jnp.split(proj, [r, r + N], axis=-1)
+    if cfg.ssm_inner_norm:
+        dt_low = _rms(dt_low, p["dt_norm"], cfg.norm_eps)
+        Bt = _rms(Bt, p["b_norm"], cfg.norm_eps)
+        Ct = _rms(Ct, p["c_norm"], cfg.norm_eps)
     dt = jax.nn.softplus(dt_low @ p["dt_proj"].astype(jnp.float32)
                          + p["dt_bias"])                  # (B, L, di)
     return dt, Bt, Ct
@@ -137,39 +163,49 @@ def mamba_block(p, x, cfg: ArchConfig, use_kernel: bool = False):
     return y @ p["out_proj"]
 
 
-def mamba_prefill(p, x, cfg: ArchConfig):
-    """Like ``mamba_block`` but also returns the decode state."""
-    K = cfg.ssm_conv
+def mask_tail(dt, x, n_valid):
+    """Zero ``dt`` and ``x`` at positions ``>= n_valid``: with ``dt = 0``
+    the step leaves ``h`` as it was (``exp(0) = 1``, no input term), so a
+    padded chunk tail never folds into the recurrent state."""
+    real = (jnp.arange(x.shape[1]) < n_valid)[None, :, None]
+    return jnp.where(real, dt, 0.0), jnp.where(real, x, 0)
+
+
+def mamba_chunk(p, x, cfg: ArchConfig, state: MambaState, n_valid=None):
+    """Multi-token step from ``state``.  x: (B, L, d) -> (B, L, d) and the
+    state as of token ``n_valid - 1`` (``n_valid`` may be traced; None
+    means all ``L`` tokens are real).
+
+    The conv tail is prepended to the chunk's conv inputs and the scan
+    starts from ``state.ssm``.  Past ``n_valid`` the outputs are
+    meaningless and the state ignores them: ``dt`` and ``x`` are zeroed
+    there, and the new tail is the last ``K - 1`` REAL conv inputs, taken
+    across the chunk boundary when the chunk is shorter than the tail."""
+    K, L = cfg.ssm_conv, x.shape[1]
     xz = x @ p["in_proj"]
-    xi, z = jnp.split(xz, 2, axis=-1)
-    conv_in = xi
-    xi = jax.nn.silu(_causal_conv(xi, p["conv_w"], p["conv_b"]))
-    dt, Bt, Ct = _ssm_inputs(p, xi, cfg)
+    xi, z = jnp.split(xz, 2, axis=-1)                     # (B, L, di) each
+    window = jnp.concatenate([state.conv.astype(xi.dtype), xi], axis=1)
+    xc = jax.nn.silu(_causal_conv(xi, p["conv_w"], p["conv_b"],
+                                  tail=state.conv))
+    dt, Bt, Ct = _ssm_inputs(p, xc, cfg)
+    if n_valid is None:
+        n_valid = L
+    else:
+        dt, xc = mask_tail(dt, xc, n_valid)
     A = -jnp.exp(p["A_log"])
-    y, h = selective_scan(xi, dt, Bt, Ct, A, p["D"])
+    with jax.named_scope("ssm_scan"):
+        y, h = selective_scan(xc, dt, Bt, Ct, A, p["D"], h0=state.ssm)
     y = y.astype(x.dtype) * jax.nn.silu(z)
-    tail = conv_in[:, -(K - 1):, :] if K > 1 \
-        else jnp.zeros((x.shape[0], 0, cfg.d_inner), x.dtype)
+    tail = jax.lax.dynamic_slice_in_dim(window, n_valid, K - 1, axis=1)
     return y @ p["out_proj"], MambaState(conv=tail, ssm=h)
 
 
-def mamba_decode(p, x, cfg: ArchConfig, state: MambaState):
-    """Single-token step.  x: (B, 1, d) -> (B, 1, d), new state."""
-    xz = x @ p["in_proj"]
-    xi, z = jnp.split(xz, 2, axis=-1)                     # (B, 1, di)
-    window = jnp.concatenate([state.conv, xi], axis=1)    # (B, K, di)
-    conv = jnp.einsum("bkd,kd->bd", window, p["conv_w"]) + p["conv_b"]
-    xi_t = jax.nn.silu(conv)[:, None, :]                  # (B, 1, di)
-    dt, Bt, Ct = _ssm_inputs(p, xi_t, cfg)
-    A = -jnp.exp(p["A_log"])
-    da = jnp.exp(dt[:, 0, :, None] * A)                   # (B, di, N)
-    h = da * state.ssm + (dt[:, 0, :] * xi_t[:, 0].astype(jnp.float32))[..., None] \
-        * Bt[:, 0, None, :]
-    y = jnp.einsum("bdn,bn->bd", h, Ct[:, 0]) \
-        + xi_t[:, 0].astype(jnp.float32) * p["D"]
-    y = y[:, None, :].astype(x.dtype) * jax.nn.silu(z)
-    new_state = MambaState(conv=window[:, 1:, :], ssm=h)
-    return y @ p["out_proj"], new_state
+def state_bytes(cfg: ArchConfig) -> int:
+    """Bytes of one slot's ``MambaState`` in one layer: the conv tail in
+    the model's dtype and the recurrent state in float32."""
+    di = cfg.d_inner
+    return (cfg.ssm_conv - 1) * di * _dtype(cfg).itemsize \
+        + di * cfg.ssm_state * 4
 
 
 def init_mamba_state(cfg: ArchConfig, batch: int) -> MambaState:

@@ -17,11 +17,11 @@ not ``n_slots * max_len``.
       - a paged decode step, jitted ONCE with the pool donated: per-slot
         gather through the block table -> the exact dense decode math ->
         one scatter of the new token's K/V rows back into the pool;
-      - **chunked prefill admission** (attention archs): the prompt
-        streams through one compiled ``block_size``-token chunk step,
-        allocating its block right before the chunk runs — one compile
-        TOTAL instead of one per prefill bucket, and O(block) activation
-        memory per admission;
+      - **chunked prefill admission** (every arch): the prompt streams
+        through one compiled ``block_size``-token chunk step, allocating
+        its block right before the chunk runs — one compile TOTAL instead
+        of one per prefill bucket or prompt length, and O(block)
+        activation memory per admission;
       - block free / reuse on eos / length retirement, with admission
         backpressure (a request waits in FIFO order while the pool lacks
         blocks) and a clear :class:`PoolExhausted` error for requests
@@ -32,11 +32,13 @@ Token-for-token greedy parity with the dense engine is pinned in
 same ``max_len`` width the dense step sees, so masked (causally dead)
 positions contribute exact zeros either way.
 
-SSM caveat: mamba/SSM recurrent states are O(1) per slot and stay dense
-(there is nothing to page); SSM archs also admit via one exact-length
-prefill whose KV (hybrid archs) is scattered into blocks afterwards —
-CHUNKED-compute prefill is excluded for them because the recurrent state
-cannot resume mid-prompt from a cache row.
+Two kinds of state live side by side.  Attention KV is paged; mamba/SSM
+recurrent states (conv tail + ``(d_inner, N)`` state) are O(1) per slot
+and stay dense, one row per slot.  The chunk step carries both across
+chunks: at attention positions it gathers and writes KV blocks, at mamba
+positions it reads the slot's row, runs the chunk from it, and writes the
+state as of the chunk's last REAL token back (``mamba.mamba_chunk``: the
+padded tail of the last chunk never folds into the state).
 """
 from __future__ import annotations
 
@@ -143,9 +145,7 @@ class PagedContinuousEngine(ContinuousEngine):
         self._decode_paged = jax.jit(self._decode_slots_paged,
                                      donate_argnums=donate)
         self._prefill_chunk = jax.jit(self._prefill_chunk_step,
-                                      donate_argnums=(1,))
-        self._write_paged = jax.jit(self._write_paged_step,
-                                    donate_argnums=donate)
+                                      donate_argnums=donate)
 
     # ---------------------------------------------------------- pool state
     def _make_pools(self):
@@ -244,8 +244,9 @@ class PagedContinuousEngine(ContinuousEngine):
                                               self.max_len)[0]
                     caches_b.append(jax.tree.map(lambda x: x[:, None], g))
                 elif spec.mixer == "mamba":
-                    caches_b.append(jax.tree.map(lambda x: x[:, None],
-                                                 dense_s[i]))
+                    with jax.named_scope("ssm_state"):
+                        caches_b.append(jax.tree.map(lambda x: x[:, None],
+                                                     dense_s[i]))
                 else:
                     caches_b.append(None)
             logits, new = self.model.decode_step(
@@ -283,58 +284,61 @@ class PagedContinuousEngine(ContinuousEngine):
                         lambda P, r: P.at[:, blk, off].set(r), pl, row))
         return logits, new_pools, new_dense
 
-    def _prefill_chunk_step(self, params, pools, table_s, tok, pos):
-        """One ``block_size``-token prompt chunk for ONE slot (attention
-        archs): gather the slot's cache at full padded width, run the
-        multi-token decode step at positions ``pos .. pos + bs - 1``, and
-        scatter the chunk's K/V block back.  Compiled ONCE for the whole
-        deployment — there are no prefill buckets to compile."""
+    def _prefill_chunk_step(self, params, pools, dense, table_s, slot, tok,
+                            pos, last):
+        """One ``block_size``-token prompt chunk for ONE slot, any arch:
+        run the multi-token decode step at positions ``pos .. pos + bs -
+        1`` from the slot's state, and return the logits of the chunk's
+        last real token (index ``last`` in the chunk, traced) with the
+        updated pools and dense states.  Attention positions gather the
+        slot's cache at full padded width and scatter the chunk's K/V
+        block back; mamba positions read the slot's dense row (zeros for
+        a prompt's first chunk: the row may hold a retired request's
+        state) and write back the state as of token ``last``.  Compiled
+        ONCE for the whole deployment — no prefill buckets, no
+        per-length compiles."""
         bs = self.block_size
         width = self._max_blocks * bs     # chunk write must fit un-clamped
-        caches_b = [None if g is None
-                    else jax.tree.map(lambda x: x[:, None], g)
-                    for g in self._gather_slot(pools, table_s, width)]
+        caches_b = []
+        for i, spec in enumerate(self._pattern):
+            if spec.mixer == "attn":
+                with jax.named_scope("kv_gather"):
+                    g = self._gather_slot([pools[i]], table_s, width)[0]
+                caches_b.append(jax.tree.map(lambda x: x[:, None], g))
+            elif spec.mixer == "mamba":
+                with jax.named_scope("ssm_state"):
+                    caches_b.append(jax.tree.map(
+                        lambda x: jnp.where(
+                            pos == 0, jnp.zeros((), x.dtype),
+                            jax.lax.dynamic_slice_in_dim(x, slot, 1,
+                                                         axis=1)),
+                        dense[i]))
+            else:
+                caches_b.append(None)
         logits, new = self.model.decode_step(
-            params, caches_b, {"tokens": tok[None]}, pos)
+            params, caches_b, {"tokens": tok[None]}, pos, n_valid=last + 1)
         blk = table_s[pos // bs]
-        new_pools = []
-        for pl, nc in zip(pools, new):
-            if pl is None:
-                new_pools.append(None)
-                continue
-            new_pools.append(jax.tree.map(
-                lambda P, x: P.at[:, blk].set(
-                    jax.lax.dynamic_slice_in_dim(x[:, 0], pos, bs, axis=1)),
-                pl, nc))
-        return logits, new_pools
-
-    def _write_paged_step(self, pools, dense, new, blk_ids, slot):
-        """Admit one EXACT-length prefilled request (SSM / hybrid archs):
-        scatter each attention cache's first ``len(blk_ids)`` blocks of
-        rows into the pool, write recurrent states into the slot's dense
-        row.  ``new`` leaves are ``max_len``-padded (the shared prefill);
-        only the prompt's blocks are taken, so pool use tracks S."""
-        bs = self.block_size
-        n_chunks = blk_ids.shape[0]
         new_pools, new_dense = [], []
         for i, spec in enumerate(self._pattern):
             if spec.mixer == "attn":
-                def put(P, x):
-                    rows = x[:, 0, :n_chunks * bs]
-                    rows = rows.reshape(x.shape[0], n_chunks, bs,
-                                        *x.shape[3:])
-                    return P.at[:, blk_ids].set(rows)
-                new_pools.append(jax.tree.map(put, pools[i], new[i]))
-                new_dense.append(dense[i])
+                with jax.named_scope("kv_scatter"):
+                    new_pools.append(jax.tree.map(
+                        lambda P, x: P.at[:, blk].set(
+                            jax.lax.dynamic_slice_in_dim(x[:, 0], pos, bs,
+                                                         axis=1)),
+                        pools[i], new[i]))
+                new_dense.append(None)
             elif spec.mixer == "mamba":
+                with jax.named_scope("ssm_state"):
+                    new_dense.append(jax.tree.map(
+                        lambda D, x: jax.lax.dynamic_update_slice_in_dim(
+                            D, x, slot, axis=1),
+                        dense[i], new[i]))
                 new_pools.append(None)
-                new_dense.append(jax.tree.map(
-                    lambda C, c: C.at[:, slot].set(c[:, 0]),
-                    dense[i], new[i]))
             else:
                 new_pools.append(None)
                 new_dense.append(None)
-        return new_pools, new_dense
+        return logits, new_pools, new_dense
 
     # ------------------------------------------------------- host control
     def _blocks_needed(self, req: Request) -> int:
@@ -372,8 +376,6 @@ class PagedContinuousEngine(ContinuousEngine):
             raise PoolExhausted(           # _can_admit gates this
                 f"admitting request {req.rid} without pool room "
                 "(engine bug)")
-        if self._exact_prefill:
-            return self._admit_exact(req, slot)
         n_chunks = _cdiv(S, bs)
         logits = None
         for j in range(n_chunks):
@@ -381,32 +383,14 @@ class PagedContinuousEngine(ContinuousEngine):
             chunk = np.zeros(bs, dtype=np.int32)
             part = req.tokens[j * bs:(j + 1) * bs]
             chunk[:len(part)] = part
-            logits, self._pools = self._prefill_chunk(
-                self.params, self._pools, jnp.asarray(self._tables[slot]),
-                jnp.asarray(chunk), jnp.asarray(j * bs, jnp.int32))
+            logits, self._pools, self._dense = self._prefill_chunk(
+                self.params, self._pools, self._dense,
+                jnp.asarray(self._tables[slot]), np.int32(slot),
+                jnp.asarray(chunk), np.int32(j * bs),
+                np.int32(len(part) - 1))
         key = f"prefill_chunk@{bs}"
         self.stats.prefills_by_bucket[key] = \
             self.stats.prefills_by_bucket.get(key, 0) + n_chunks
-        last = (S - 1) - (n_chunks - 1) * bs
-        return logits[:, last:last + 1]
-
-    def _admit_exact(self, req: Request, slot: int):
-        """SSM/hybrid admission: one exact-length prefill (the recurrent
-        state cannot resume mid-prompt), then block-granular scatter."""
-        S = len(req.tokens)
-        logits, new = self._prefill(
-            self.params, {"tokens": jnp.asarray(req.tokens[None])},
-            last_index=jnp.asarray([S - 1], jnp.int32))
-        blk_ids = [self._alloc_block(slot, req.rid)
-                   for _ in range(_cdiv(S, self.block_size))] \
-            if any(s.mixer == "attn" for s in self._pattern) else []
-        self._pools, self._dense = self._write_paged(
-            self._pools, self._dense, new,
-            jnp.asarray(np.asarray(blk_ids, dtype=np.int32)),
-            np.int32(slot))
-        key = f"prefill@{S}"
-        self.stats.prefills_by_bucket[key] = \
-            self.stats.prefills_by_bucket.get(key, 0) + 1
         return logits
 
     def _grow_blocks(self) -> None:
@@ -439,34 +423,34 @@ class PagedContinuousEngine(ContinuousEngine):
     # ------------------------------------------------------ advisor bridge
     def compiled_steps(self, buckets=None) -> dict:
         """Every step this deployment runs, compiled without executing:
-        the paged decode plus either the single chunk-prefill step
-        (attention archs) or one exact-length prefill per seen length
-        (SSM archs, ``buckets`` overrides)."""
+        the paged decode and the single chunk-prefill step, for every
+        arch (``buckets`` is ignored: there are none)."""
         p_struct = jax.tree.map(
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), self.params)
-        pools = jax.eval_shape(self._make_pools)
-        dense = jax.eval_shape(self._make_dense)
-        tables = jax.ShapeDtypeStruct((self.n_slots, self._max_blocks),
-                                      jnp.int32)
-        tokens = jax.ShapeDtypeStruct((self.n_slots, 1), jnp.int32)
-        pos = jax.ShapeDtypeStruct((self.n_slots,), jnp.int32)
+        pools, dense, tables, tokens, pos = self._step_structs()
         out = {"decode": self._decode_paged.lower(
             p_struct, pools, dense, tables, tokens, pos).compile()}
-        if self._exact_prefill:
-            for L in tuple(sorted(buckets or self._seen_buckets)) \
-                    or (self.max_len,):
-                tok = jax.ShapeDtypeStruct((1, L), jnp.int32)
-                idx = jax.ShapeDtypeStruct((1,), jnp.int32)
-                out[f"prefill@{L}"] = self._prefill.lower(
-                    p_struct, {"tokens": tok}, last_index=idx).compile()
-        else:
-            row = jax.ShapeDtypeStruct((self._max_blocks,), jnp.int32)
-            tok = jax.ShapeDtypeStruct((self.block_size,), jnp.int32)
-            p0 = jax.ShapeDtypeStruct((), jnp.int32)
-            out[f"prefill_chunk@{self.block_size}"] = \
-                self._prefill_chunk.lower(
-                    p_struct, pools, row, tok, p0).compile()
+        out[f"prefill_chunk@{self.block_size}"] = self._prefill_chunk.lower(
+            p_struct, pools, dense, *self._chunk_structs()).compile()
         return out
+
+    def _step_structs(self):
+        """Abstract pools, dense states, tables, tokens and positions of
+        the decode step."""
+        return (jax.eval_shape(self._make_pools),
+                jax.eval_shape(self._make_dense),
+                jax.ShapeDtypeStruct((self.n_slots, self._max_blocks),
+                                     jnp.int32),
+                jax.ShapeDtypeStruct((self.n_slots, 1), jnp.int32),
+                jax.ShapeDtypeStruct((self.n_slots,), jnp.int32))
+
+    def _chunk_structs(self):
+        """Abstract table row, slot, chunk tokens, position and last index
+        of the chunk step."""
+        i32 = jax.ShapeDtypeStruct((), jnp.int32)
+        return (jax.ShapeDtypeStruct((self._max_blocks,), jnp.int32), i32,
+                jax.ShapeDtypeStruct((self.block_size,), jnp.int32), i32,
+                i32)
 
 
 # --------------------------------------------------------------------------
@@ -488,26 +472,18 @@ def _ircheck_engine() -> PagedContinuousEngine:
 def _ircheck_paged_decode_spec():
     from ..analysis.ircheck import EntrySpec
     eng = _ircheck_engine()
-    pools = jax.eval_shape(eng._make_pools)
-    dense = jax.eval_shape(eng._make_dense)
-    tables = jax.ShapeDtypeStruct((eng.n_slots, eng._max_blocks), jnp.int32)
-    tokens = jax.ShapeDtypeStruct((eng.n_slots, 1), jnp.int32)
-    pos = jax.ShapeDtypeStruct((eng.n_slots,), jnp.int32)
     return EntrySpec(name="serve.paged_decode", fn=eng._decode_paged,
-                     args=(eng.params, pools, dense, tables, tokens, pos),
+                     args=(eng.params, *eng._step_structs()),
                      donate_argnums=(1,))
 
 
 def _ircheck_paged_prefill_spec():
     from ..analysis.ircheck import EntrySpec
     eng = _ircheck_engine()
-    pools = jax.eval_shape(eng._make_pools)
-    row = jax.ShapeDtypeStruct((eng._max_blocks,), jnp.int32)
-    tok = jax.ShapeDtypeStruct((eng.block_size,), jnp.int32)
-    pos = jax.ShapeDtypeStruct((), jnp.int32)
+    pools, dense = eng._step_structs()[:2]
     return EntrySpec(name="serve.paged_prefill_chunk",
                      fn=eng._prefill_chunk,
-                     args=(eng.params, pools, row, tok, pos),
+                     args=(eng.params, pools, dense, *eng._chunk_structs()),
                      donate_argnums=(1,))
 
 

@@ -16,7 +16,8 @@ prompt/output lengths — the orchestration this module owns:
     moment a sequence finishes;
   * a host-side FIFO request queue plus occupancy telemetry
     (``ServeStats``);
-  * profiler spans of each scheduler tick (``repro.serve.step``) and its
+  * profiler spans of each scheduler tick (``repro.serve.step``, with the
+    slots' recurrent ``state_bytes`` among its stats) and its
     parts — ``repro.serve.admit`` per admission, ``repro.serve.decode``
     (host work up to the decode dispatch), ``repro.serve.sync`` (the
     sampled tokens' wait and copy) and ``repro.serve.emit`` (emit, retire,
@@ -38,6 +39,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..models import blocks, mamba
 from ..models.lm import LanguageModel
 from .engine import sample_logits
 
@@ -66,7 +68,8 @@ class ServeStats:
     ``MultiSweepResult.predicted_speedup(weights=)``.  The ``kv_bytes_*``
     fields are populated by the paged engine (0 on the dense engines):
     peak pool bytes actually allocated vs the dense ``n_slots * max_len``
-    equivalent."""
+    equivalent.  ``state_bytes`` is the recurrent state the slots in use
+    held at the last step (the ``repro.serve.step`` span's stat)."""
 
     n_slots: int
     decode_steps: int = 0        # jitted (n_slots, max_len) steps executed
@@ -77,6 +80,8 @@ class ServeStats:
     prefills_by_bucket: dict = field(default_factory=dict)
     kv_bytes_peak: int = 0       # paged: peak allocated pool bytes
     kv_bytes_dense: int = 0      # dense-equivalent n_slots * max_len bytes
+    state_bytes: int = 0         # recurrent (mamba) state bytes of the
+                                 # slots in use at the last step
 
     @property
     def occupancy(self) -> float:
@@ -141,6 +146,8 @@ class ContinuousEngine:
         self._sample = jax.jit(
             functools.partial(sample_logits, temperature=self.temperature))
         self._seen_buckets = set(self.prefill_buckets)
+        self._slot_state_bytes = mamba.state_bytes(cfg) * sum(
+            spec.mixer == "mamba" for spec in blocks.layer_specs(cfg))
         self._reset()
 
     # ------------------------------------------------------------- jitted
@@ -321,11 +328,13 @@ class ContinuousEngine:
                 admitted += 1
             active = [s for s in range(self.n_slots)
                       if self._slot_req[s] is not None]
+            self.stats.state_bytes = len(active) * self._slot_state_bytes
             # live: the positions the decode attends over, summed
             span.set_metadata(
                 admitted=admitted, chunks=self.stats.prefill_calls - calls,
                 active=len(active),
-                live=int(self._pos[active].sum()) + len(active))
+                live=int(self._pos[active].sum()) + len(active),
+                state_bytes=self.stats.state_bytes)
             if not active:
                 return False
             with jax.profiler.TraceAnnotation("repro.serve.decode"):

@@ -1,7 +1,8 @@
 """Paged-KV continuous batching tests: greedy parity with the dense
-engines (pinned acceptance tests, exact + staggered arrivals + SSM),
-KV-bytes scaling with actual sequence lengths, block free/reuse after
-eos retirement, pool-exhaustion admission errors, and backpressure."""
+engines (pinned acceptance tests, exact + staggered arrivals + chunked
+SSM / hybrid prefill), KV-bytes scaling with actual sequence lengths,
+block free/reuse after eos retirement, pool-exhaustion admission errors,
+and backpressure."""
 import jax
 import numpy as np
 import pytest
@@ -144,9 +145,12 @@ def test_step_weights_reflect_observed_mix(model_params):
 @pytest.mark.parametrize("arch", ["falcon-mamba-7b", "jamba-v0.1-52b"])
 def test_ssm_archs_paged_parity(arch):
     """SSM / hybrid archs: recurrent state stays dense (O(1) per slot —
-    nothing to page) and admission uses ONE exact-length prefill, since
-    the recurrent state cannot resume mid-prompt; attention KV (hybrid)
-    is still block-scattered.  Greedy outputs match the static engine."""
+    nothing to page) and admission streams through the same chunk step
+    as attention archs, the recurrent state resuming from the slot's row
+    at every chunk (7-token prompts: a full chunk, then a padded one);
+    attention KV (hybrid) is block-paged.  Three requests on two slots,
+    so a reused slot starts its prompt from zero state.  Greedy outputs
+    match the static engine."""
     cfg = ARCHS[arch].reduced()
     model = make_model(cfg, moe_impl="dense")
     params = model.init(KEY)
@@ -159,9 +163,21 @@ def test_ssm_archs_paged_parity(arch):
     outs = eng.run([(prompts[i], 5, i) for i in range(3)])
     for i in range(3):
         np.testing.assert_array_equal(outs[i], ref[i])
-    assert eng._exact_prefill                 # chunked prefill excluded
+    assert eng.stats.prefills_by_bucket == {"prefill_chunk@4": 6}
     if arch == "falcon-mamba-7b":
         assert eng.block_bytes == 0           # no attention KV at all
     else:
         assert eng.kv_bytes_peak > 0          # hybrid pages its attn KV
     assert eng._pool.in_use == 0
+
+
+@pytest.mark.parametrize("arch", ["qwen2.5-3b", "falcon-mamba-7b",
+                                  "jamba-v0.1-52b"])
+def test_compiled_steps_every_arch(arch):
+    """Every arch deploys the same two steps: the paged decode and the one
+    chunk-prefill step (no per-length prefill for SSM archs)."""
+    cfg = ARCHS[arch].reduced()
+    model = make_model(cfg, moe_impl="dense")
+    eng = PagedContinuousEngine(model=model, params=model.init(KEY),
+                                n_slots=2, max_len=16, block_size=4)
+    assert set(eng.compiled_steps()) == {"decode", "prefill_chunk@4"}
