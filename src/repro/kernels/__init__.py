@@ -8,6 +8,8 @@ Mosaic kernel on TPU, the Pallas interpreter elsewhere (how the CPU test
 suite runs the real kernel bodies against the oracles).
 
   flash_attention/  blockwise online-softmax attention (GQA, causal)
+  paged_attention/  single-token decode attention straight from a paged
+                    KV pool through the block table (live blocks only)
   mamba_scan/       selective-scan recurrence (channel-blocked, VMEM state)
   halo_exchange/    message-free ring exchange via async remote DMA +
                     semaphore handshake — the paper's mechanism as a kernel

@@ -207,13 +207,7 @@ def stack_prefill(stacked, x, cfg: ArchConfig, max_len: int, positions=None,
                 new_caches.append(state)
             else:
                 new_caches.append(None)
-            if spec.ffn == "dense":
-                h = layers.rms_norm(x, p["ffn_norm"], cfg.norm_eps)
-                x = x + layers.mlp_block(p["mlp"], h, cfg)
-            elif spec.ffn == "moe":
-                h = layers.rms_norm(x, p["ffn_norm"], cfg.norm_eps)
-                y, _ = moe.moe_ffn(p["moe"], h, cfg, impl=moe_impl)
-                x = x + y
+            x = _serve_ffn(p, spec, x, cfg, moe_impl)
         return x, tuple(new_caches)
 
     if cfg.scan_layers:
@@ -255,13 +249,7 @@ def stack_decode(stacked, caches, x, cfg: ArchConfig, pos,
                 new_caches.append(state)
             else:
                 new_caches.append(None)
-            if spec.ffn == "dense":
-                h = layers.rms_norm(x, p["ffn_norm"], cfg.norm_eps)
-                x = x + layers.mlp_block(p["mlp"], h, cfg)
-            elif spec.ffn == "moe":
-                h = layers.rms_norm(x, p["ffn_norm"], cfg.norm_eps)
-                y, _ = moe.moe_ffn(p["moe"], h, cfg, impl=moe_impl)
-                x = x + y
+            x = _serve_ffn(p, spec, x, cfg, moe_impl)
         return x, tuple(new_caches)
 
     # caches with None entries can't ride through lax.scan xs; substitute
@@ -302,3 +290,68 @@ def stack_decode(stacked, caches, x, cfg: ArchConfig, pos,
                 new_caches.append(
                     jax.tree.map(lambda *xs: jnp.stack(xs), *entries))
     return x, list(new_caches)
+
+
+def stack_decode_paged(stacked, pools, dense, x, cfg: ArchConfig, pos,
+                       tables, moe_impl: str = "scatter", act_pspec=None):
+    """One decode token per slot through the stack, attention KV paged.
+
+    x: (B, 1, d); pos: (B,) each slot's write index; tables: (B,
+    max_blocks) pool block ids.  ``pools`` holds, per pattern position,
+    {"k", "v": (n_blocks, n_pool_blocks, Hkv, block_size, D)} at attention
+    positions (``None`` elsewhere); they ride the layer scan's carry and
+    every layer writes its new rows into them in place
+    (:func:`layers.attention_decode_paged`), so no layer's pool is ever
+    sliced out or returned whole.  ``dense`` holds the slots' mamba
+    states, stacked over the layer blocks, at mamba positions.  Returns
+    (x, pools, dense)."""
+    pattern = layer_pattern(cfg)
+
+    def block_body(carry, scanned):
+        x, pools = carry
+        layer, block_params, block_dense = scanned
+        x = _pin_act(x, act_pspec)
+        pools, new_dense = list(pools), []
+        for i, (spec, p) in enumerate(zip(pattern, block_params)):
+            new_dense.append(None)
+            if spec.mixer == "attn":
+                h = layers.rms_norm(x, p["mixer_norm"], cfg.norm_eps)
+                out, pools[i] = layers.attention_decode_paged(
+                    p["attn"], h, cfg, pools[i], layer, tables, pos)
+                x = x + out
+            elif spec.mixer == "mamba":
+                h = layers.rms_norm(x, p["mixer_norm"], cfg.norm_eps)
+                out, new_dense[i] = mamba.mamba_chunk(p["mamba"], h, cfg,
+                                                      block_dense[i])
+                x = x + out
+            x = _serve_ffn(p, spec, x, cfg, moe_impl)
+        return (x, tuple(pools)), tuple(new_dense)
+
+    if cfg.scan_layers:
+        (x, pools), dense = jax.lax.scan(
+            block_body, (x, tuple(pools)),
+            (jnp.arange(n_blocks(cfg)), tuple(stacked), tuple(dense)))
+        return x, list(pools), list(dense)
+    collected = []
+    for i in range(n_blocks(cfg)):
+        block = [jax.tree.map(lambda a: a[i], s) for s in stacked]
+        bd = [None if d is None else jax.tree.map(lambda a: a[i], d)
+              for d in dense]
+        (x, pools), d = block_body((x, tuple(pools)), (i, block, bd))
+        collected.append(d)
+    dense = [None if entries[0] is None
+             else jax.tree.map(lambda *xs: jnp.stack(xs), *entries)
+             for entries in zip(*collected)]
+    return x, list(pools), dense
+
+
+def _serve_ffn(p, spec: LayerSpec, x, cfg: ArchConfig, moe_impl: str):
+    """The layer's FFN half on the serving paths (MoE aux loss dropped)."""
+    if spec.ffn == "dense":
+        h = layers.rms_norm(x, p["ffn_norm"], cfg.norm_eps)
+        x = x + layers.mlp_block(p["mlp"], h, cfg)
+    elif spec.ffn == "moe":
+        h = layers.rms_norm(x, p["ffn_norm"], cfg.norm_eps)
+        y, _ = moe.moe_ffn(p["moe"], h, cfg, impl=moe_impl)
+        x = x + y
+    return x

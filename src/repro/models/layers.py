@@ -287,6 +287,34 @@ def attention_decode(p, x, cfg: ArchConfig, cache_k, cache_v, pos):
     return out @ p["wo"], cache_k, cache_v
 
 
+def attention_decode_paged(p, x, cfg: ArchConfig, pool, layer, tables, pos):
+    """One decode token per slot against a paged KV pool.
+
+    x: (B, 1, d); pool: {"k", "v": (n_layers, n_pool_blocks, Hkv,
+    block_size, D)}, stacked over the layer blocks; layer: this layer's
+    index in it; tables: (B, max_blocks) pool block ids; pos: (B,) each
+    slot's write index.  Writes every slot's new K/V row at ``(table[pos //
+    block_size], pos % block_size)`` in place, then attends positions
+    ``0 .. pos`` through the block table (``kernels/paged_attention``).
+    Returns (out, pool)."""
+    from ..kernels.paged_attention import ops as pa_ops
+    B = x.shape[0]
+    q, k, v = _project_qkv(p, x, cfg, pos[:, None])
+    bs = pool["k"].shape[3]
+    blk = jnp.take_along_axis(tables, (pos // bs)[:, None], axis=1)
+    head = jnp.arange(cfg.n_kv_heads)[None, :]
+    off = (pos % bs)[:, None]
+    with jax.named_scope("kv_scatter"):
+        # one D-row per (slot, head); inactive slots write row 0 of the
+        # null block 0, never attended by an active slot
+        pool = {"k": pool["k"].at[layer, blk, head, off].set(k[:, 0]),
+                "v": pool["v"].at[layer, blk, head, off].set(v[:, 0])}
+    qg = q[:, 0].reshape(B, cfg.n_kv_heads, -1, q.shape[-1])
+    out = pa_ops.paged_attention(qg, pool["k"], pool["v"], tables, pos + 1,
+                                 layer)
+    return out.reshape(B, 1, -1) @ p["wo"], pool
+
+
 # ---------------------------------------------------------------------- MLPs
 def init_mlp(cfg: ArchConfig, key, d_ff: Optional[int] = None) -> dict:
     d = cfg.d_model

@@ -143,6 +143,18 @@ class LanguageModel:
             x = jax.lax.dynamic_slice_in_dim(x, n_valid - 1, 1, axis=1)
         return self._head(params, x), caches
 
+    def decode_step_paged(self, params, pools, dense, batch, pos, tables):
+        """One new token per slot with attention KV in a paged pool
+        (``blocks.stack_decode_paged``).  ``batch`` carries ``{"tokens":
+        (B, 1)}``; ``pos`` ``(B,)`` each slot's write index; ``tables``
+        ``(B, max_blocks)`` its pool block ids.  Returns ``(logits (B, 1,
+        V), pools, dense)``."""
+        x = self._embed_inputs(params, batch)
+        x, pools, dense = blocks.stack_decode_paged(
+            params["stack"], pools, dense, x, self.cfg, pos, tables,
+            moe_impl=self.moe_impl, act_pspec=self.act_pspec)
+        return self._head(params, x), pools, dense
+
     def init_caches(self, batch_size: int, max_len: int):
         return blocks.init_caches(self.cfg, batch_size, max_len)
 

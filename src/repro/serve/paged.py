@@ -14,9 +14,11 @@ not ``n_slots * max_len``.
     target of inactive slots and the read target of unallocated logical
     blocks, both rendered inert by the causal mask).
   * ``PagedContinuousEngine`` — drop-in ``ContinuousEngine`` with
-      - a paged decode step, jitted ONCE with the pool donated: per-slot
-        gather through the block table -> the exact dense decode math ->
-        one scatter of the new token's K/V rows back into the pool;
+      - a paged decode step, jitted ONCE with the pool donated: one
+        batched pass over the layer scan in which every attention layer
+        writes each slot's new K/V row into the pool in place and attends
+        only the slot's live blocks through the block table
+        (``kernels/paged_attention``) — no per-slot full-width copy;
       - **chunked prefill admission** (every arch): the prompt streams
         through one compiled ``block_size``-token chunk step, allocating
         its block right before the chunk runs — one compile TOTAL instead
@@ -28,9 +30,8 @@ not ``n_slots * max_len``.
         that could never fit.
 
 Token-for-token greedy parity with the dense engine is pinned in
-``tests/test_paged.py``: the gathered per-slot cache is sliced to the
-same ``max_len`` width the dense step sees, so masked (causally dead)
-positions contribute exact zeros either way.
+``tests/test_paged.py``: the kernel attends exactly the positions the
+dense step leaves unmasked.
 
 Two kinds of state live side by side.  Attention KV is paged; mamba/SSM
 recurrent states (conv tail + ``(d_inner, N)`` state) are O(1) per slot
@@ -131,6 +132,7 @@ class PagedContinuousEngine(ContinuousEngine):
             raise ValueError(f"block_size must be >= 1: {self.block_size}")
         cfg = self.model.cfg
         self._pattern = blocks_lib.layer_pattern(cfg)
+        self._has_kv = any(s.mixer == "attn" for s in self._pattern)
         self._nb = blocks_lib.n_blocks(cfg)
         self._max_blocks = _cdiv(self.max_len, self.block_size)
         if not self.pool_blocks:
@@ -150,13 +152,15 @@ class PagedContinuousEngine(ContinuousEngine):
     # ---------------------------------------------------------- pool state
     def _make_pools(self):
         """KV pools, one per attention pattern position: ``{"k"/"v":
-        (n_layer_blocks, pool_blocks + 1, block_size, Hkv, D)}`` (+1 for
-        the null block 0); ``None`` elsewhere."""
+        (n_layer_blocks, pool_blocks + 1, Hkv, block_size, D)}`` (+1 for
+        the null block 0); ``None`` elsewhere.  A pool block is one
+        contiguous ``(Hkv, block_size, D)`` run whose per-head tile is
+        ``(block_size, D)``: what the decode kernel DMAs and tiles."""
         cfg = self.model.cfg
         dt = jnp.dtype(cfg.dtype)
         hd = cfg.resolved_head_dim
-        shape = (self._nb, self.pool_blocks + 1, self.block_size,
-                 cfg.n_kv_heads, hd)
+        shape = (self._nb, self.pool_blocks + 1, cfg.n_kv_heads,
+                 self.block_size, hd)
         return [{"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
                 if spec.mixer == "attn" else None for spec in self._pattern]
 
@@ -208,81 +212,26 @@ class PagedContinuousEngine(ContinuousEngine):
         return self.n_slots * self._max_blocks * self.block_bytes
 
     # ------------------------------------------------------------- jitted
-    def _gather_slot(self, pools, table_s, width):
-        """Per-slot caches through the block table: each attention pool
-        gathers the slot's blocks and flattens to ``(nb, width, Hkv, D)``
-        (``width <= max_blocks * block_size``; unallocated logical blocks
-        read the null block — causally masked)."""
-        out = []
-        for pl in pools:
-            if pl is None:
-                out.append(None)
-                continue
-            leaf = {}
-            for name, P in pl.items():
-                g = P[:, table_s]                       # (nb, mb, bs, H, D)
-                g = g.reshape(g.shape[0], -1, *g.shape[3:])
-                leaf[name] = g[:, :width]
-            out.append(leaf)
+    def _gather_slot(self, pool, table_s):
+        """One slot's cache through its block table row: the pool's
+        blocks gathered into the dense layout ``(nb, max_blocks *
+        block_size, Hkv, D)`` (unallocated logical blocks read the null
+        block — causally masked)."""
+        out = {}
+        for name, P in pool.items():
+            g = P[:, table_s].swapaxes(2, 3)        # (nb, mb, bs, H, D)
+            out[name] = g.reshape(g.shape[0], -1, *g.shape[3:])
         return out
 
     def _decode_slots_paged(self, params, pools, dense, tables, tokens, pos):
-        """One decode step for ALL slots against the shared pool: vmap of
-        (gather -> dense single-token decode -> extract the written row),
-        then ONE scatter of every slot's new K/V rows into the pool.  The
-        gathered view is sliced to the dense step's ``max_len`` width, so
-        the math (and greedy tokens) matches the dense engine exactly."""
-        bs = self.block_size
-        in_ax = jax.tree.map(lambda _: 1, dense)
-
-        def one(table_s, dense_s, tok, p):
-            caches_b = []
-            for i, spec in enumerate(self._pattern):
-                if spec.mixer == "attn":
-                    with jax.named_scope("kv_gather"):
-                        g = self._gather_slot([pools[i]], table_s,
-                                              self.max_len)[0]
-                    caches_b.append(jax.tree.map(lambda x: x[:, None], g))
-                elif spec.mixer == "mamba":
-                    with jax.named_scope("ssm_state"):
-                        caches_b.append(jax.tree.map(lambda x: x[:, None],
-                                                     dense_s[i]))
-                else:
-                    caches_b.append(None)
-            logits, new = self.model.decode_step(
-                params, caches_b, {"tokens": tok[None]}, p)
-            rows, new_dense = [], []
-            for i, spec in enumerate(self._pattern):
-                if spec.mixer == "attn":
-                    rows.append(jax.tree.map(
-                        lambda x: jax.lax.dynamic_slice_in_dim(
-                            x[:, 0], p, 1, axis=1)[:, 0], new[i]))
-                    new_dense.append(None)
-                elif spec.mixer == "mamba":
-                    rows.append(None)
-                    new_dense.append(jax.tree.map(lambda x: x[:, 0], new[i]))
-                else:
-                    rows.append(None)
-                    new_dense.append(None)
-            return logits[0], rows, new_dense
-
-        logits, rows, new_dense = jax.vmap(
-            one, in_axes=(0, in_ax, 0, 0),
-            out_axes=(0, 1, in_ax))(tables, dense, tokens, pos)
-
-        blk = jnp.take_along_axis(tables, (pos // bs)[:, None], axis=1)[:, 0]
-        off = pos % bs
-        new_pools = []
-        with jax.named_scope("kv_scatter"):
-            for pl, row in zip(pools, rows):
-                if pl is None:
-                    new_pools.append(None)
-                else:
-                    # row leaves: (nb, n_slots, H, D); inactive slots write
-                    # their (null) table[0] block — harmless by construction
-                    new_pools.append(jax.tree.map(
-                        lambda P, r: P.at[:, blk, off].set(r), pl, row))
-        return logits, new_pools, new_dense
+        """One decode step for ALL slots against the shared pool, one
+        batched pass over the layer scan (``LanguageModel.decode_step_paged``):
+        each attention layer writes every slot's new K/V row into the
+        (donated) pool in place and attends only the slot's blocks below
+        ``pos + 1`` through the block table; mamba layers step the slots'
+        dense rows."""
+        return self.model.decode_step_paged(params, pools, dense,
+                                            {"tokens": tokens}, pos, tables)
 
     def _prefill_chunk_step(self, params, pools, dense, table_s, slot, tok,
                             pos, last):
@@ -298,12 +247,11 @@ class PagedContinuousEngine(ContinuousEngine):
         ONCE for the whole deployment — no prefill buckets, no
         per-length compiles."""
         bs = self.block_size
-        width = self._max_blocks * bs     # chunk write must fit un-clamped
         caches_b = []
         for i, spec in enumerate(self._pattern):
             if spec.mixer == "attn":
                 with jax.named_scope("kv_gather"):
-                    g = self._gather_slot([pools[i]], table_s, width)[0]
+                    g = self._gather_slot(pools[i], table_s)
                 caches_b.append(jax.tree.map(lambda x: x[:, None], g))
             elif spec.mixer == "mamba":
                 with jax.named_scope("ssm_state"):
@@ -324,8 +272,8 @@ class PagedContinuousEngine(ContinuousEngine):
                 with jax.named_scope("kv_scatter"):
                     new_pools.append(jax.tree.map(
                         lambda P, x: P.at[:, blk].set(
-                            jax.lax.dynamic_slice_in_dim(x[:, 0], pos, bs,
-                                                         axis=1)),
+                            jax.lax.dynamic_slice_in_dim(
+                                x[:, 0], pos, bs, axis=1).swapaxes(1, 2)),
                         pools[i], new[i]))
                 new_dense.append(None)
             elif spec.mixer == "mamba":
@@ -404,6 +352,18 @@ class PagedContinuousEngine(ContinuousEngine):
             if self._pos[slot] // self.block_size \
                     >= len(self._slot_blocks[slot]):
                 self._alloc_block(slot, req.rid)
+
+    def _decode_reads(self, active) -> dict:
+        """The KV blocks this step's attention reads, Σ over the active
+        slots of ``ceil((pos + 1) / block_size)``, against a full-width
+        read of ``n_slots * max_blocks``; both added to ``ServeStats``."""
+        if not self._has_kv:
+            return {"kv_blocks_read": 0, "kv_blocks_full": 0}
+        read = int((self._pos[active] // self.block_size + 1).sum())
+        full = self.n_slots * self._max_blocks
+        self.stats.kv_blocks_read += read
+        self.stats.kv_blocks_full += full
+        return {"kv_blocks_read": read, "kv_blocks_full": full}
 
     def _decode_active(self):
         self._grow_blocks()

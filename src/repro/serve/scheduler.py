@@ -19,9 +19,11 @@ prompt/output lengths — the orchestration this module owns:
   * profiler spans of each scheduler tick (``repro.serve.step``, with the
     slots' recurrent ``state_bytes`` among its stats) and its
     parts — ``repro.serve.admit`` per admission, ``repro.serve.decode``
-    (host work up to the decode dispatch), ``repro.serve.sync`` (the
-    sampled tokens' wait and copy) and ``repro.serve.emit`` (emit, retire,
-    release).  Their stats are host integers only, so tracing adds no sync.
+    (host work up to the decode dispatch; the paged engine adds the KV
+    blocks it reads, ``kv_blocks_read`` / ``kv_blocks_full``),
+    ``repro.serve.sync`` (the sampled tokens' wait and copy) and
+    ``repro.serve.emit`` (emit, retire, release).  Their stats are host
+    integers only, so tracing adds no sync.
 
 The compiled steps of a deployment (every prefill bucket + the decode
 step) are exactly what the batched advisor prices in one call:
@@ -69,7 +71,10 @@ class ServeStats:
     fields are populated by the paged engine (0 on the dense engines):
     peak pool bytes actually allocated vs the dense ``n_slots * max_len``
     equivalent.  ``state_bytes`` is the recurrent state the slots in use
-    held at the last step (the ``repro.serve.step`` span's stat)."""
+    held at the last step (the ``repro.serve.step`` span's stat).
+    ``kv_blocks_read`` / ``kv_blocks_full`` sum the ``repro.serve.decode``
+    span's stats of the same names (paged engine; 0 on the dense ones):
+    their ratio is the share of a full-width read the decodes moved."""
 
     n_slots: int
     decode_steps: int = 0        # jitted (n_slots, max_len) steps executed
@@ -82,6 +87,8 @@ class ServeStats:
     kv_bytes_dense: int = 0      # dense-equivalent n_slots * max_len bytes
     state_bytes: int = 0         # recurrent (mamba) state bytes of the
                                  # slots in use at the last step
+    kv_blocks_read: int = 0      # paged: Σ KV blocks the decodes read
+    kv_blocks_full: int = 0      # paged: Σ n_slots * max_blocks per decode
 
     @property
     def occupancy(self) -> float:
@@ -301,6 +308,12 @@ class ContinuousEngine:
         while the block pool lacks room (blocks free as slots retire)."""
         return True
 
+    def _decode_reads(self, active) -> dict:
+        """Stats of the ``repro.serve.decode`` span about the cache the
+        decode reads (paged engine overrides; the dense step reads every
+        slot's whole row)."""
+        return {}
+
     def _decode_active(self):
         """Dispatch the jitted decode step over all slots; returns its
         (device) logits (paged engine overrides: block-table growth +
@@ -337,7 +350,8 @@ class ContinuousEngine:
                 state_bytes=self.stats.state_bytes)
             if not active:
                 return False
-            with jax.profiler.TraceAnnotation("repro.serve.decode"):
+            with jax.profiler.TraceAnnotation(
+                    "repro.serve.decode", **self._decode_reads(active)):
                 logits = self._decode_active()
             with jax.profiler.TraceAnnotation("repro.serve.sync"):
                 # decode keys live in the upper uint32 half; prefill keys

@@ -107,6 +107,70 @@ def test_eos_retirement_frees_and_reuses_blocks(model_params, static):
     assert not eng._tables.any()              # all rows back to null block
 
 
+def test_kv_block_reads_sum_the_live_blocks(model_params):
+    """Staggered arrivals on two slots: ``ServeStats`` sums, step by step,
+    the blocks each active slot's attention reads, ``ceil((pos + 1) /
+    block_size)``, against ``n_slots * max_blocks`` for a full-width
+    read."""
+    eng = _paged(model_params)
+    reads = []
+    decode = eng._decode_active
+
+    def watched():
+        reads.append(sum(int(eng._pos[s]) // BS + 1
+                         for s in range(eng.n_slots)
+                         if eng._slot_req[s] is not None))
+        return decode()
+
+    eng._decode_active = watched
+    prompts = _prompts(9, 4, 11)
+    eng.run([(prompts[0][:3], 7, 0), (prompts[1], 4, 1),
+             (prompts[2][:6], 9, 2), (prompts[3][:1], 5, 6)])
+    assert len(reads) == eng.stats.decode_steps > 0
+    assert eng.stats.kv_blocks_read == sum(reads)
+    assert eng.stats.kv_blocks_full == \
+        eng.stats.decode_steps * 2 * (MAX_LEN // BS)
+    assert 0 < eng.stats.kv_blocks_read < eng.stats.kv_blocks_full
+
+
+def _large_values(fn, args, limit):
+    """Shapes of every array of ``limit`` elements or more that the traced
+    program computes, sub-programs included; a Pallas kernel counts by its
+    outputs (its body runs in the chip's on-core memory)."""
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            found.extend(tuple(v.aval.shape) for v in eqn.outvars
+                         if np.prod(v.aval.shape) >= limit)
+            if eqn.primitive.name == "pallas_call":
+                continue
+            for p in eqn.params.values():
+                for sub in p if isinstance(p, (list, tuple)) else (p,):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return found
+
+
+def test_paged_decode_builds_no_full_width_cache():
+    """The reduced engine the IR checker lowers: apart from the pools
+    (updated in place), the decode step computes no array as large as
+    every slot's cache at full width, ``n_slots * max_len * Hkv * D``."""
+    from repro.serve.paged import _ircheck_engine
+    eng = _ircheck_engine()
+    cfg = eng.model.cfg
+    pools = eng._step_structs()[0]
+    pool_shapes = {tuple(x.shape) for x in jax.tree.leaves(pools)}
+    limit = eng.n_slots * eng.max_len * cfg.n_kv_heads \
+        * cfg.resolved_head_dim
+    big = _large_values(eng._decode_paged,
+                        (eng.params, *eng._step_structs()), limit)
+    assert big and set(big) <= pool_shapes, set(big) - pool_shapes
+
+
 def test_pool_exhaustion_raises_at_submit(model_params):
     """A request that could NEVER fit fails fast at submit() with a clear
     error, before anything is queued."""

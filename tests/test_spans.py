@@ -66,13 +66,14 @@ def recorded(tmp_path_factory):
                              cxl_lat_ns=(250.0, 700.0))
     eng = _engine()
     eng.run([(np.arange(1, 4), 2)])               # compile outside the trace
-    seen = []
+    seen, reads = [], []
     decode = eng._decode_active
 
     def watched():
         active = [s for s in range(eng.n_slots)
                   if eng._slot_req[s] is not None]
         seen.append((len(active), int(sum(eng._pos[s] + 1 for s in active))))
+        reads.append(sum(int(eng._pos[s]) // BS + 1 for s in active))
         return decode()
 
     eng._decode_active = watched
@@ -111,7 +112,8 @@ def recorded(tmp_path_factory):
     spans = _read_spans(out)
     by = {k: [s for s in spans if any(s.inside(m) for m in v)]
           for k, v in marks.items()}
-    return {"by": by, "seen": seen, "engine": eng, "rids": rids,
+    return {"by": by, "seen": seen, "reads": reads, "engine": eng,
+            "rids": rids,
             "grid": grid, "results": results}
 
 
@@ -205,6 +207,21 @@ def test_serve_step_spans_match_engine_state(recorded):
         parts = _named(spans, name)
         assert len(parts) == 4
         assert all(any(p.inside(s) for s in steps) for p in parts)
+
+
+def test_decode_span_counts_the_kv_blocks_read(recorded):
+    """``kv_blocks_read``: Σ over the active slots of ``ceil((pos + 1) /
+    block_size)``; ``kv_blocks_full``: ``n_slots * max_blocks``; the
+    engine's ``ServeStats`` sum both over every decode it ran."""
+    eng = recorded["engine"]
+    decodes = _named(recorded["by"]["serve"], "repro.serve.decode")
+    assert [d.stats["kv_blocks_read"] for d in decodes] == recorded["reads"]
+    assert all(d.stats["kv_blocks_full"] == 2 * -(-24 // BS)
+               for d in decodes)
+    # the warm-up run before the trace: one decode of one slot at pos 3
+    assert eng.stats.kv_blocks_read == 1 + sum(recorded["reads"])
+    assert eng.stats.kv_blocks_full == \
+        eng.stats.decode_steps * 2 * -(-24 // BS)
 
 
 def test_queued_stamp_precedes_admission(recorded):

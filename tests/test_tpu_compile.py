@@ -84,6 +84,24 @@ def test_flash_attention_compiles_at_qwen_heads(one_chip):
                                      interpret=False), q, kv, kv)
 
 
+@pytest.mark.parametrize("n_kv,group,block,max_blocks,layers", [
+    (QWEN_KV, QWEN_HEADS // QWEN_KV, 64, 40, 36),      # qwen2.5-3b serving
+    (1, 20, 256, 34, 2)], ids=["qwen", "jamba"])       # jamba2-3b serving
+def test_paged_attention_compiles_at_serving_widths(one_chip, n_kv, group,
+                                                    block, max_blocks,
+                                                    layers):
+    from repro.kernels.paged_attention import paged_attention
+    slots = 32
+    pool = _spec((layers, slots * max_blocks + 1, n_kv, block, QWEN_HD),
+                 "bfloat16", one_chip)
+    _assert_kernel(functools.partial(paged_attention, interpret=False),
+                   _spec((slots, n_kv, group, QWEN_HD), "bfloat16",
+                         one_chip), pool, pool,
+                   _spec((slots, max_blocks), "int32", one_chip),
+                   _spec((slots,), "int32", one_chip),
+                   _spec((), "int32", one_chip))
+
+
 def test_mamba_scan_compiles_at_falcon_mamba_widths(one_chip):
     from repro.kernels.mamba_scan import mamba_scan
     x = _spec((1, MAMBA_L, MAMBA_D_INNER), "float32", one_chip)
