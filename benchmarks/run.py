@@ -9,27 +9,15 @@ import argparse
 import time
 
 from . import (advisor, fig5_stencil, fig7_multinode, fig8_breakdown,
-               fig9_hpcg, fig10_hpcg_breakdown, roofline, serve_throughput,
-               sweep_grid)
+               fig9_hpcg, fig10_hpcg_breakdown, roofline)
 
 SECTIONS = [
     ("Fig5: stencil reference vs model", fig5_stencil.run),
     ("Fig7: multi-node CXL.mem prediction (1.37x/1.59x claims)",
      fig7_multinode.run),
-    # also times every sweep backend and writes BENCH_sweep.json
-    ("Fig7 sensitivity: scenario-sweep grid + backend benchmark",
-     sweep_grid.run),
     ("Fig8: stencil overhead breakdown", fig8_breakdown.run),
     ("Fig9: HPCG reference vs model", fig9_hpcg.run),
     ("Fig10: HPCG overhead breakdown", fig10_hpcg_breakdown.run),
-    # static vs continuous engines, dense run kept off the JSON so the
-    # paged record below (the committed/regression-gated mode) wins
-    ("Serving throughput: static vs continuous batching",
-     lambda quick: serve_throughput.run(quick=quick, json_path="")),
-    # paged-KV engine + seeded Poisson load generator; writes
-    # BENCH_serve.json (kv_bytes, p50/p99 latency, TTFT, SLO attainment)
-    ("Serving throughput: paged KV + load generator",
-     lambda quick: serve_throughput.run(quick=quick, paged=True)),
 ]
 
 

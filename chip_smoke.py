@@ -123,8 +123,7 @@ def phase_serve(jax, seed: int) -> None:
     from repro.configs import get_arch
     from repro.models import factory
     from repro.models.reference import reference_logits
-    from repro.serve.paged import PagedContinuousEngine
-    from repro.serve.scheduler import ServeStats
+    from repro.serve.paged import PagedContinuousEngine, ServeStats
 
     cfg = get_arch(SERVE_ARCH)
     say(f"[serve] {cfg.name}: {cfg.n_layers} layers, d_model {cfg.d_model}, "

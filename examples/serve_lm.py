@@ -2,7 +2,7 @@
 
 Static engine (one batch, ends together):
     PYTHONPATH=src python examples/serve_lm.py --new-tokens 24
-Continuous batching (slots + queue, staggered arrivals):
+Continuous batching (slots + queue, paged KV, staggered arrivals):
     PYTHONPATH=src python examples/serve_lm.py --continuous
 """
 import argparse
@@ -13,7 +13,7 @@ import numpy as np
 
 from repro.configs import get_arch
 from repro.models.factory import make_model
-from repro.serve import ContinuousEngine, ServeEngine
+from repro.serve import PagedContinuousEngine, ServeEngine
 
 
 def main():
@@ -24,6 +24,8 @@ def main():
     ap.add_argument("--new-tokens", type=int, default=24)
     ap.add_argument("--temperature", type=float, default=0.8)
     ap.add_argument("--continuous", action="store_true")
+    ap.add_argument("--block-size", type=int, default=16)
+    ap.add_argument("--pool-blocks", type=int, default=0)
     args = ap.parse_args()
 
     cfg = get_arch(args.arch).reduced()
@@ -35,10 +37,10 @@ def main():
         cfg.vocab_size)
 
     if args.continuous:
-        engine = ContinuousEngine(model=model, params=params,
-                                  n_slots=max(2, args.batch // 2),
-                                  max_len=max_len,
-                                  temperature=args.temperature)
+        engine = PagedContinuousEngine(
+            model=model, params=params, n_slots=max(2, args.batch // 2),
+            max_len=max_len, temperature=args.temperature,
+            block_size=args.block_size, pool_blocks=args.pool_blocks)
         # stagger arrivals and vary lengths — the scheduler keeps the decode
         # slots busy while requests come and go
         reqs = [(np.asarray(prompts)[i], args.new_tokens - 3 * (i % 3), 2 * i)

@@ -26,8 +26,8 @@ HLO passes (built on :mod:`repro.core.hlo`)
   * ``donation-dead`` — parses ``input_output_alias`` from the compiled
     module and fails when a declared ``donate_argnums`` produced NO alias
     for any of that argument's flattened parameters (the donation
-    silently bought nothing; the scheduler's two donated jits are the
-    prime targets).
+    silently bought nothing; the serve engine's two pool-donating jits
+    are the prime targets).
   * ``collective-mesh`` — replica-group sizes of every collective must be
     a product of the entry's registered mesh axis sizes; single-member
     collectives are flagged as degenerate (pure overhead).
@@ -40,7 +40,7 @@ mirrors ``repro.analysis.lint.register_rule`` and
 LAZY builder returning an :class:`EntrySpec` (args as
 ``jax.ShapeDtypeStruct``\\ s: everything is traced/lowered, nothing is
 executed).  Builtin entries self-register from their owning modules
-(``repro.core.sweep_kernel``, ``repro.serve.scheduler``,
+(``repro.core.sweep_kernel``, ``repro.serve.paged``,
 ``repro.launch.train``) via a ``register_ircheck_entrypoints(register)``
 hook, so the checker never hard-codes their configurations.
 
@@ -92,7 +92,7 @@ class EntrySpec:
 
     ``fn`` is either a plain callable (ircheck wraps it in ``jax.jit``
     with ``donate_argnums``) or an already-jitted object (anything with a
-    ``.lower`` method — e.g. the scheduler's ``self._decode``; then
+    ``.lower`` method — e.g. the serve engine's ``self._decode_paged``; then
     ``donate_argnums`` must restate what the jit was built with, for the
     donation pass).  ``args``/``kwargs`` are abstract values
     (``jax.ShapeDtypeStruct`` pytrees) or small concrete arrays — either
@@ -129,8 +129,8 @@ _BUILTINS_LOADED = False
 #: ``register_ircheck_entrypoints(register)`` and registers its own
 #: representative configurations (lazy builders, so importing ircheck
 #: never traces anything).
-_BUILTIN_PROVIDERS = ("repro.core.sweep_kernel", "repro.serve.scheduler",
-                      "repro.serve.paged", "repro.launch.train")
+_BUILTIN_PROVIDERS = ("repro.core.sweep_kernel", "repro.serve.paged",
+                      "repro.launch.train")
 
 
 def register_entrypoint(name: str, builder=None, *, min_devices: int = 1,

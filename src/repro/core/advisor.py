@@ -244,7 +244,7 @@ class CommAdvisor:
         batched call: the engine's steps (prefill buckets + decode) are
         compiled once via ``engine.compiled_steps()`` and priced together.
         Works with both ``serve.ServeEngine`` and the continuous
-        ``serve.ContinuousEngine`` — and is itself a shim over
+        ``serve.PagedContinuousEngine`` — and is itself a shim over
         ``price(engine, grid)``; legacy execution kwargs are DEPRECATED."""
         plan = legacy_plan(plan, "CommAdvisor.sweep_serve", backend=backend,
                            chunk_scenarios=chunk_scenarios,

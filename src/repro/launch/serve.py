@@ -1,6 +1,7 @@
-"""Serving driver: batched generation with the static or continuous engine,
-plus an optional CXL-scenario pricing pass over the deployment's
-collectives (``--price-sweep``, the ``price(engine, grid)`` front door)."""
+"""Serving driver: batched generation with the static engine or the
+continuous-batching engine (paged KV, ``--continuous``), plus an optional
+CXL-scenario pricing pass over the deployment's collectives
+(``--price-sweep``, the ``price(engine, grid)`` front door)."""
 from __future__ import annotations
 
 import argparse
@@ -13,7 +14,7 @@ import numpy as np
 from ..configs import get_arch
 from ..models import factory
 from ..serve.engine import ServeEngine
-from ..serve.scheduler import ContinuousEngine, ServeStats
+from ..serve.paged import PagedContinuousEngine, ServeStats
 from .compile_cache import use_compile_cache
 
 
@@ -49,17 +50,14 @@ def main(argv=None) -> int:
     ap.add_argument("--eos-id", type=int, default=None,
                     help="retire sequences that sample this token")
     ap.add_argument("--continuous", action="store_true",
-                    help="continuous-batching scheduler (slots + queue) "
-                         "instead of the static batch")
-    ap.add_argument("--paged", action="store_true",
-                    help="block/paged KV cache from a shared pool "
-                         "(implies --continuous)")
+                    help="continuous batching (slots + queue, paged KV "
+                         "from a shared pool) instead of the static batch")
     ap.add_argument("--block-size", type=int, default=16,
-                    help="tokens per KV block for --paged (also the "
+                    help="tokens per KV block for --continuous (also the "
                          "chunked-prefill chunk length)")
     ap.add_argument("--pool-blocks", type=int, default=0,
-                    help="shared KV pool size for --paged (0: the dense "
-                         "equivalent, no admission backpressure)")
+                    help="shared KV pool size for --continuous (0: one "
+                         "max_len row per slot, no admission backpressure)")
     ap.add_argument("--slots", type=int, default=0,
                     help="decode slots for --continuous (default: --batch)")
     ap.add_argument("--price-sweep", action="store_true",
@@ -84,21 +82,13 @@ def main(argv=None) -> int:
                                 (args.batch, args.prompt_len), 0,
                                 cfg.vocab_size)
 
-    if args.continuous or args.paged:
-        if args.paged:
-            from ..serve.paged import PagedContinuousEngine
-            engine = PagedContinuousEngine(
-                model=model, params=params,
-                n_slots=args.slots or args.batch, max_len=max_len,
-                temperature=args.temperature, eos_id=args.eos_id,
-                block_size=args.block_size, pool_blocks=args.pool_blocks)
-        else:
-            engine = ContinuousEngine(model=model, params=params,
-                                      n_slots=args.slots or args.batch,
-                                      max_len=max_len,
-                                      temperature=args.temperature,
-                                      eos_id=args.eos_id)
-        # warmup: compile the prefill bucket + decode step off the clock
+    if args.continuous:
+        engine = PagedContinuousEngine(
+            model=model, params=params, n_slots=args.slots or args.batch,
+            max_len=max_len, temperature=args.temperature,
+            eos_id=args.eos_id, block_size=args.block_size,
+            pool_blocks=args.pool_blocks)
+        # warmup: compile the chunk step + decode step off the clock
         engine.run([(np.asarray(prompt)[0], 2)])
         engine.stats = ServeStats(n_slots=engine.n_slots)  # drop warmup stats
         t0 = time.perf_counter()
@@ -110,10 +100,9 @@ def main(argv=None) -> int:
         print(f"generated {len(outs)} requests / {n_tok} tokens in "
               f"{dt:.2f}s ({n_tok / dt:.1f} tok/s, occupancy "
               f"{s.occupancy:.2f}, {s.decode_steps} decode steps)")
-        if args.paged:
-            frac = engine.kv_bytes_peak / max(engine.kv_bytes_dense, 1)
-            print(f"kv bytes: peak {engine.kv_bytes_peak} vs dense "
-                  f"{engine.kv_bytes_dense} ({frac:.0%} of the dense cache)")
+        frac = engine.kv_bytes_peak / max(engine.kv_bytes_dense, 1)
+        print(f"kv bytes: peak {engine.kv_bytes_peak} vs dense "
+              f"{engine.kv_bytes_dense} ({frac:.0%} of the dense cache)")
         print("sample:", outs[0][:16].tolist())
         if args.price_sweep:
             _price_deployment(engine, args.price_backend)

@@ -104,28 +104,13 @@ class LanguageModel:
         return ce + 0.01 * aux
 
     # --------------------------------------------------------------- serving
-    def prefill(self, params, batch, max_len: int, last_index=None):
-        """Process the prompt; returns (last-position logits, caches).
-
-        ``last_index`` (optional, ``(B,)`` int) selects the position whose
-        logits are returned instead of the final one — the bucketed-prefill
-        path of the continuous-batching scheduler right-pads prompts to a
-        bucket length, so the "last real token" sits at ``prompt_len - 1``,
-        not at ``-1``.  Causal attention makes positions ``< prompt_len``
-        independent of the padding, and the stale cache rows at padded
-        positions are overwritten by decode before they are ever attended.
-        """
+    def prefill(self, params, batch, max_len: int):
+        """Process the prompt; returns (last-position logits, caches)."""
         x = self._embed_inputs(params, batch)
         x, caches = blocks.stack_prefill(
             params["stack"], x, self.cfg, max_len, moe_impl=self.moe_impl,
             act_pspec=self.act_pspec)
-        if last_index is None:
-            x_last = x[:, -1:]
-        else:
-            idx = jnp.asarray(last_index).reshape(-1, 1, 1)
-            x_last = jnp.take_along_axis(
-                x, jnp.broadcast_to(idx, (x.shape[0], 1, x.shape[2])), axis=1)
-        return self._head(params, x_last), caches
+        return self._head(params, x[:, -1:]), caches
 
     def decode_step(self, params, caches, batch, pos, n_valid=None):
         """New tokens from the caches.  ``batch`` carries the inputs at

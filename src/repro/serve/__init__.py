@@ -1,22 +1,16 @@
 """Serving substrate: batched prefill/decode with KV + SSM caches.
 
-Three engines: the static-batch ``ServeEngine`` (one prefill, one decode
-loop, batch ends together), the continuous-batching ``ContinuousEngine``
-(fixed decode slots, bucketed prefill admission, eos/length retirement,
-request queue + occupancy telemetry), and the ``PagedContinuousEngine``
-(block/paged KV from a shared pool via a block table, chunked prefill
-admission, block free/reuse on retirement — KV bytes scale with actual
-sequence lengths, not ``n_slots * max_len``).  ``loadgen`` generates
-deterministic Poisson / trace-replay workloads and reduces runs into
-p50/p99 latency, TTFT, and SLO-attainment reports.
+Two engines: the static-batch ``ServeEngine`` (one prefill, one decode
+loop, batch ends together; also the multimodal path and the greedy oracle
+the continuous engine is tested against), and the continuous-batching
+``PagedContinuousEngine`` (fixed decode slots fed from a request queue,
+block/paged KV from a shared pool via a block table, chunked prefill
+admission, eos/length retirement with block free/reuse, occupancy
+telemetry in ``ServeStats``).
 """
 from .engine import ServeEngine, sample_logits
-from .loadgen import (LengthDist, LoadReport, Workload, poisson_workload,
-                      replay_workload, run_workload)
-from .paged import BlockPool, PagedContinuousEngine, PoolExhausted
-from .scheduler import ContinuousEngine, Request, ServeStats
+from .paged import (BlockPool, PagedContinuousEngine, PoolExhausted, Request,
+                    ServeStats)
 
-__all__ = ["ServeEngine", "sample_logits", "ContinuousEngine", "Request",
-           "ServeStats", "PagedContinuousEngine", "BlockPool",
-           "PoolExhausted", "LengthDist", "LoadReport", "Workload",
-           "poisson_workload", "replay_workload", "run_workload"]
+__all__ = ["ServeEngine", "sample_logits", "PagedContinuousEngine",
+           "BlockPool", "PoolExhausted", "Request", "ServeStats"]

@@ -3,8 +3,8 @@
 The decode step is the unit the ``decode_*`` / ``long_*`` dry-run cells
 lower: one new token against a full-length cache.  Generation here drives
 that step in a host loop with greedy/temperature sampling; requests are
-batched (static batch — continuous batching is an orchestration concern
-above this layer).
+batched (static batch; continuous batching is ``serve/paged.py``'s
+``PagedContinuousEngine``).
 """
 from __future__ import annotations
 
@@ -85,17 +85,13 @@ class ServeEngine:
                                 out[0].dtype))
         return jnp.concatenate(out, axis=1)
 
-    def decode_throughput_step(self, caches, batch, pos):
-        """Expose the raw jitted decode step (benchmarks / dry-run)."""
-        return self._decode(self.params, caches, batch, pos)
-
     def compiled_steps(self, batch_size: int = 1, prompt_len: int = 32
                        ) -> dict:
         """Compile (without executing) this engine's steps for the advisor:
         ``{"prefill@L": compiled, "decode": compiled}`` — the artifacts
         ``repro.core.price(engine_or_steps, grid)`` prices as one batched
-        deployment (see ``serve.scheduler.ContinuousEngine.compiled_steps``
-        for the multi-bucket continuous analog)."""
+        deployment (see ``serve.paged.PagedContinuousEngine.compiled_steps``
+        for the continuous engine's decode and chunk steps)."""
         if self.model.cfg.frontend is not None:
             raise ValueError("compiled_steps lowers a {'tokens': (B, L)} "
                              "batch — token LMs only (multimodal batches "

@@ -1,37 +1,42 @@
-"""Paged (block) KV cache for continuous-batching serving.
+"""The continuous-batching serving engine: paged KV, chunked prefill.
 
-The dense ``ContinuousEngine`` allocates one ``(n_slots, max_len)`` cache
-row per slot, so a single long request prices every short request at
-``max_len`` memory.  This module stores attention KV in fixed-size
-**blocks** drawn from one shared pool instead (the PagedAttention idea,
-Kwon et al.): each slot owns a chain of blocks, a **block table** maps the
-slot's logical block index to its pool block id, and total KV bytes scale
-with the sum of ACTUAL sequence lengths rounded up to the block size —
-not ``n_slots * max_len``.
+``ServeEngine`` (``serve/engine.py``) runs a STATIC batch: every request
+prefills together, decodes together, and the batch ends when the longest
+request does.  A serving deployment instead sees requests arrive over
+time with different prompt and output lengths; ``PagedContinuousEngine``
+is the one engine that serves them:
 
-  * ``BlockPool`` — host-side free-list + reservation accounting over pool
-    block ids (block 0 is the null block: never allocated, the write
-    target of inactive slots and the read target of unallocated logical
-    blocks, both rendered inert by the causal mask).
-  * ``PagedContinuousEngine`` — drop-in ``ContinuousEngine`` with
-      - a paged decode step, jitted ONCE with the pool donated: one
-        batched pass over the layer scan in which every attention layer
-        writes each slot's new K/V row into the pool in place and attends
-        only the slot's live blocks through the block table
-        (``kernels/paged_attention``) — no per-slot full-width copy;
-      - **chunked prefill admission** (every arch): the prompt streams
-        through one compiled ``block_size``-token chunk step, allocating
-        its block right before the chunk runs — one compile TOTAL instead
-        of one per prefill bucket or prompt length, and O(block)
-        activation memory per admission;
-      - block free / reuse on eos / length retirement, with admission
-        backpressure (a request waits in FIFO order while the pool lacks
-        blocks) and a clear :class:`PoolExhausted` error for requests
-        that could never fit.
-
-Token-for-token greedy parity with the dense engine is pinned in
-``tests/test_paged.py``: the kernel attends exactly the positions the
-dense step leaves unmasked.
+  * a fixed set of decode slots fed from a host-side FIFO request queue;
+    eos / length retirement frees a slot, and its KV blocks, for the next
+    queued request the moment a sequence finishes (``ServeStats`` keeps
+    the occupancy telemetry);
+  * attention KV in fixed-size **blocks** drawn from one shared pool (the
+    PagedAttention idea, Kwon et al.): each slot owns a chain of blocks, a
+    **block table** maps the slot's logical block index to its pool block
+    id, and KV bytes scale with the sum of ACTUAL sequence lengths rounded
+    up to the block size, not ``n_slots * max_len``.  ``BlockPool`` keeps
+    the free list and the reservations (block 0 is the null block: never
+    allocated, the write target of inactive slots and the read target of
+    unallocated logical blocks, both rendered inert by the causal mask);
+  * a paged decode step, jitted ONCE with the pool donated: one batched
+    pass over the layer scan in which every attention layer writes each
+    slot's new K/V row into the pool in place and attends only the slot's
+    live blocks through the block table (``kernels/paged_attention``);
+  * **chunked prefill admission** (every arch): the prompt streams through
+    one compiled ``block_size``-token chunk step, allocating its block
+    right before the chunk runs: one compile for the deployment, whatever
+    the prompt lengths, and O(block) activation memory per admission;
+  * admission backpressure (a request waits in FIFO order while the pool
+    lacks blocks) and a clear :class:`PoolExhausted` error for requests
+    that could never fit;
+  * profiler spans of each scheduler tick (``repro.serve.step``, with the
+    slots' recurrent ``state_bytes`` among its stats) and its parts:
+    ``repro.serve.admit`` per admission, ``repro.serve.decode`` (host work
+    up to the decode dispatch, with the KV blocks it reads,
+    ``kv_blocks_read`` / ``kv_blocks_full``), ``repro.serve.sync`` (the
+    sampled tokens' wait and copy) and ``repro.serve.emit`` (emit, retire,
+    release).  Their stats are host integers only, so tracing adds no
+    sync.
 
 Two kinds of state live side by side.  Attention KV is paged; mamba/SSM
 recurrent states (conv tail + ``(d_inner, N)`` state) are O(1) per slot
@@ -40,10 +45,17 @@ chunks: at attention positions it gathers and writes KV blocks, at mamba
 positions it reads the slot's row, runs the chunk from it, and writes the
 state as of the chunk's last REAL token back (``mamba.mamba_chunk``: the
 padded tail of the last chunk never folds into the state).
+
+Token-for-token greedy parity with the static engine, for attention, SSM
+and hybrid archs, is pinned in ``tests/test_scheduler.py`` and
+``tests/test_paged.py``.  The deployment's two compiled steps are what
+``repro.core.price(engine, grid)`` prices in one batched call.
 """
 from __future__ import annotations
 
-from dataclasses import dataclass
+import functools
+import time
+from dataclasses import dataclass, field
 
 import jax
 import jax.numpy as jnp
@@ -51,7 +63,63 @@ import numpy as np
 
 from ..models import blocks as blocks_lib
 from ..models import mamba as mamba_lib
-from .scheduler import ContinuousEngine, Request
+from ..models.lm import LanguageModel
+from .engine import sample_logits
+
+
+@dataclass
+class Request:
+    """One generation request.  ``arrival`` is the engine step index at
+    which the request becomes visible to the scheduler (0 = immediately);
+    ``rid`` is assigned by ``submit``."""
+
+    tokens: np.ndarray                 # (S,) prompt token ids
+    max_new_tokens: int
+    arrival: int = 0
+    rid: int = -1
+
+
+@dataclass
+class ServeStats:
+    """Occupancy telemetry of everything the engine has run.
+
+    ``prefills_by_bucket`` counts prefill programs dispatched per compiled
+    step (keyed like ``compiled_steps()``: ``"prefill_chunk@bs"``), so
+    together with ``decode_steps`` it is the observed step mix that
+    :meth:`PagedContinuousEngine.step_weights` feeds back into
+    ``MultiSweepResult.predicted_speedup(weights=)``.  ``kv_bytes_peak``
+    is the peak pool bytes actually allocated, against
+    ``kv_bytes_dense``, the ``n_slots * max_len`` equivalent.
+    ``state_bytes`` is the recurrent state the slots in use held at the
+    last step (the ``repro.serve.step`` span's stat).  ``kv_blocks_read``
+    / ``kv_blocks_full`` sum the ``repro.serve.decode`` span's stats of
+    the same names: their ratio is the share of a full-width read the
+    decodes moved."""
+
+    n_slots: int
+    decode_steps: int = 0        # jitted (n_slots, max_len) steps executed
+    slot_steps: int = 0          # Σ active slots over those steps
+    prefills: int = 0
+    generated_tokens: int = 0
+    completed: int = 0
+    prefills_by_bucket: dict = field(default_factory=dict)
+    kv_bytes_peak: int = 0       # peak allocated pool bytes
+    kv_bytes_dense: int = 0      # dense-equivalent n_slots * max_len bytes
+    state_bytes: int = 0         # recurrent (mamba) state bytes of the
+                                 # slots in use at the last step
+    kv_blocks_read: int = 0      # Σ KV blocks the decodes read
+    kv_blocks_full: int = 0      # Σ n_slots * max_blocks per decode
+
+    @property
+    def occupancy(self) -> float:
+        """Fraction of slot-steps that did useful work (1.0 = every slot
+        active on every decode step)."""
+        return self.slot_steps / max(1, self.decode_steps * self.n_slots)
+
+    @property
+    def prefill_calls(self) -> int:
+        """Prefill chunks dispatched."""
+        return sum(self.prefills_by_bucket.values())
 
 
 def _cdiv(a: int, b: int) -> int:
@@ -114,16 +182,23 @@ class BlockPool:
 
 
 @dataclass
-class PagedContinuousEngine(ContinuousEngine):
+class PagedContinuousEngine:
     """Continuous batching over a shared block pool (see module docstring).
 
     ``block_size`` is the per-block token count (also the chunked-prefill
     chunk length); ``pool_blocks`` sizes the shared pool (0 means the
     dense equivalent ``n_slots * ceil(max_len / block_size)``, i.e. no
-    admission backpressure).  ``prefill_buckets`` is rejected for
-    attention archs — the chunk step replaces bucketed prefill entirely.
+    admission backpressure).  ``eos_id`` retires a sequence the moment it
+    samples that token; ``seed`` keys the sampler.
     """
 
+    model: LanguageModel
+    params: dict
+    n_slots: int
+    max_len: int
+    temperature: float = 0.0
+    eos_id: int | None = None
+    seed: int = 0
     block_size: int = 16
     pool_blocks: int = 0
 
@@ -131,23 +206,49 @@ class PagedContinuousEngine(ContinuousEngine):
         if self.block_size < 1:
             raise ValueError(f"block_size must be >= 1: {self.block_size}")
         cfg = self.model.cfg
+        if cfg.frontend is not None:
+            raise ValueError("PagedContinuousEngine drives token LMs; "
+                             "multimodal decode stays on the static "
+                             "ServeEngine")
         self._pattern = blocks_lib.layer_pattern(cfg)
         self._has_kv = any(s.mixer == "attn" for s in self._pattern)
         self._nb = blocks_lib.n_blocks(cfg)
         self._max_blocks = _cdiv(self.max_len, self.block_size)
         if not self.pool_blocks:
             self.pool_blocks = self.n_slots * self._max_blocks
-        super().__post_init__()
-        if self.prefill_buckets:        # SSM archs already rejected in super
-            raise ValueError(
-                "PagedContinuousEngine prefills in block_size chunks; "
-                "prefill_buckets do not apply (drop them)")
+        self._sample = jax.jit(
+            functools.partial(sample_logits, temperature=self.temperature))
+        self._slot_state_bytes = mamba_lib.state_bytes(cfg) * sum(
+            spec.mixer == "mamba" for spec in blocks_lib.layer_specs(cfg))
         donate = (1, 2) if any(s.mixer == "mamba" for s in self._pattern) \
             else (1,)                    # dense tree is all-None: no buffers
         self._decode_paged = jax.jit(self._decode_slots_paged,
                                      donate_argnums=donate)
         self._prefill_chunk = jax.jit(self._prefill_chunk_step,
                                       donate_argnums=donate)
+        self._pools = self._make_pools()
+        self._dense = self._make_dense()
+        self._tables = np.zeros((self.n_slots, self._max_blocks),
+                                dtype=np.int32)
+        self._slot_blocks = [[] for _ in range(self.n_slots)]
+        self._pool = BlockPool(self.pool_blocks)
+        self._pos = np.zeros(self.n_slots, dtype=np.int32)
+        self._tokens = np.zeros((self.n_slots, 1), dtype=np.int32)
+        self._slot_req = [None] * self.n_slots      # Request or None
+        self._emitted = np.zeros(self.n_slots, dtype=np.int64)
+        self._budget = np.zeros(self.n_slots, dtype=np.int64)
+        self._queue: list = []
+        self._order: list = []
+        self._outputs: dict = {}
+        self._next_rid = 0
+        self.stats = ServeStats(n_slots=self.n_slots)
+        #: rid -> {"queued", "visible", "first", "done"}: perf_counter
+        #: stamps at submission, when the request became visible to the
+        #: scheduler (``run``'s arrival step, or its admission when driven
+        #: through ``step``), its first token and its retirement: what a
+        #: load driver turns into TTFT and inter-token percentiles
+        self.req_times: dict = {}
+        self._key = jax.random.PRNGKey(self.seed)
 
     # ---------------------------------------------------------- pool state
     def _make_pools(self):
@@ -179,14 +280,6 @@ class PagedContinuousEngine(ContinuousEngine):
                 dense.append(None)
         return dense
 
-    def _init_cache_state(self):
-        self._pools = self._make_pools()
-        self._dense = self._make_dense()
-        self._tables = np.zeros((self.n_slots, self._max_blocks),
-                                dtype=np.int32)
-        self._slot_blocks = [[] for _ in range(self.n_slots)]
-        self._pool = BlockPool(self.pool_blocks)
-
     # ----------------------------------------------------------- kv bytes
     @property
     def block_bytes(self) -> int:
@@ -208,7 +301,7 @@ class PagedContinuousEngine(ContinuousEngine):
 
     @property
     def kv_bytes_dense(self) -> int:
-        """What the dense engine's ``(n_slots, max_len)`` rows would cost."""
+        """What one ``(n_slots, max_len)`` row per slot would cost."""
         return self.n_slots * self._max_blocks * self.block_bytes
 
     # ------------------------------------------------------------- jitted
@@ -289,24 +382,43 @@ class PagedContinuousEngine(ContinuousEngine):
         return logits, new_pools, new_dense
 
     # ------------------------------------------------------- host control
+    def submit(self, tokens, max_new_tokens: int, arrival: int = 0) -> int:
+        """Queue one request; returns its request id.  A request that
+        needs more KV blocks than the whole pool holds fails here with
+        :class:`PoolExhausted`."""
+        toks = np.asarray(tokens, dtype=np.int32).reshape(-1)
+        if len(toks) == 0:
+            raise ValueError("empty prompt")
+        if len(toks) >= self.max_len:
+            raise ValueError(f"prompt of {len(toks)} tokens leaves no room "
+                             f"to generate (max_len={self.max_len})")
+        req = Request(tokens=toks, max_new_tokens=int(max_new_tokens),
+                      arrival=int(arrival), rid=self._next_rid)
+        if req.max_new_tokens > 0:        # else nothing is ever admitted
+            need = self._blocks_needed(req)
+            if not self._pool.fits_ever(need):
+                raise PoolExhausted(
+                    f"request needs {need} KV blocks (prompt {len(toks)} "
+                    f"+ budget tokens at block_size={self.block_size}) but "
+                    f"the pool only holds {self._pool.n_blocks}; raise "
+                    "pool_blocks= or shorten the request")
+        self._next_rid += 1
+        self._order.append(req.rid)
+        now = time.perf_counter()
+        if req.max_new_tokens <= 0:       # nothing to generate: done now
+            self._outputs[req.rid] = np.zeros(0, dtype=np.int32)
+            self.req_times[req.rid] = {"queued": now, "visible": now,
+                                       "first": now, "done": now}
+            self.stats.completed += 1
+        else:
+            self.req_times[req.rid] = {"queued": now}
+            self._queue.append(req)
+        return req.rid
+
     def _blocks_needed(self, req: Request) -> int:
         S = len(req.tokens)
         budget = min(req.max_new_tokens, self.max_len - S)
         return _cdiv(S + budget, self.block_size)
-
-    def _validate_capacity(self, req: Request) -> None:
-        if req.max_new_tokens <= 0:
-            return                        # nothing is ever admitted
-        need = self._blocks_needed(req)
-        if not self._pool.fits_ever(need):
-            raise PoolExhausted(
-                f"request needs {need} KV blocks (prompt {len(req.tokens)} "
-                f"+ budget tokens at block_size={self.block_size}) but the "
-                f"pool only holds {self._pool.n_blocks}; raise pool_blocks= "
-                "or shorten the request")
-
-    def _can_admit(self, req: Request) -> bool:
-        return self._pool.available >= self._blocks_needed(req)
 
     def _alloc_block(self, slot: int, rid: int) -> int:
         blk = self._pool.alloc(rid)
@@ -318,10 +430,13 @@ class PagedContinuousEngine(ContinuousEngine):
         return blk
 
     def _prefill_into_slot(self, req: Request, slot: int):
+        """Reserve the request's worst-case blocks, stream its prompt
+        through the chunk step into ``slot`` (one block allocated right
+        before each chunk) and return the last real token's logits."""
         bs = self.block_size
         S = len(req.tokens)
         if not self._pool.try_reserve(req.rid, self._blocks_needed(req)):
-            raise PoolExhausted(           # _can_admit gates this
+            raise PoolExhausted(           # step() gates this
                 f"admitting request {req.rid} without pool room "
                 "(engine bug)")
         n_chunks = _cdiv(S, bs)
@@ -341,17 +456,52 @@ class PagedContinuousEngine(ContinuousEngine):
             self.stats.prefills_by_bucket.get(key, 0) + n_chunks
         return logits
 
-    def _grow_blocks(self) -> None:
-        """Allocate the next block for any active slot whose write position
-        crossed into an unallocated logical block (reservation-backed, so
-        this cannot fail mid-flight)."""
-        for slot in range(self.n_slots):
-            req = self._slot_req[slot]
-            if req is None:
-                continue
-            if self._pos[slot] // self.block_size \
-                    >= len(self._slot_blocks[slot]):
-                self._alloc_block(slot, req.rid)
+    def _admit(self, req: Request, slot: int) -> None:
+        S = len(req.tokens)
+        t = self.req_times[req.rid]
+        start = time.perf_counter()
+        t.setdefault("visible", start)
+        calls = self.stats.prefill_calls
+        with jax.profiler.TraceAnnotation(
+                "repro.serve.admit", prompt=S,
+                waited_us=int((start - t["queued"]) * 1e6)) as span:
+            logits = self._prefill_into_slot(req, slot)
+            key = jax.random.fold_in(self._key, req.rid)
+            tok = int(np.asarray(self._sample(logits, key))[0, 0])
+            span.set_metadata(chunks=self.stats.prefill_calls - calls)
+        self._slot_req[slot] = req
+        self._pos[slot] = S
+        self._tokens[slot, 0] = tok
+        self._budget[slot] = min(req.max_new_tokens, self.max_len - S)
+        self._emitted[slot] = 0
+        self._outputs[req.rid] = []
+        self.stats.prefills += 1
+        t["first"] = time.perf_counter()
+        self._emit(slot, tok)
+
+    def _emit(self, slot: int, tok: int) -> None:
+        req = self._slot_req[slot]
+        self._outputs[req.rid].append(tok)
+        self._emitted[slot] += 1
+        self.stats.generated_tokens += 1
+        done = self._emitted[slot] >= self._budget[slot] \
+            or (self.eos_id is not None and tok == self.eos_id)
+        if done:
+            self._retire(slot)
+
+    def _retire(self, slot: int) -> None:
+        """Finish the slot's request and return its blocks to the pool."""
+        req = self._slot_req[slot]
+        self._outputs[req.rid] = np.asarray(self._outputs[req.rid],
+                                            dtype=np.int32)
+        self._slot_req[slot] = None
+        self._pos[slot] = 0
+        self._tokens[slot, 0] = 0
+        self.req_times[req.rid]["done"] = time.perf_counter()
+        self.stats.completed += 1
+        self._pool.release(req.rid, self._slot_blocks[slot])
+        self._slot_blocks[slot] = []
+        self._tables[slot, :] = 0          # inactive slots target null
 
     def _decode_reads(self, active) -> dict:
         """The KV blocks this step's attention reads, Σ over the active
@@ -366,25 +516,110 @@ class PagedContinuousEngine(ContinuousEngine):
         return {"kv_blocks_read": read, "kv_blocks_full": full}
 
     def _decode_active(self):
-        self._grow_blocks()
+        """Allocate the next block for any active slot whose write
+        position crossed into an unallocated logical block
+        (reservation-backed, so this cannot fail mid-flight), then
+        dispatch the paged decode over all slots; returns its (device)
+        logits."""
+        for slot in range(self.n_slots):
+            req = self._slot_req[slot]
+            if req is not None and self._pos[slot] // self.block_size \
+                    >= len(self._slot_blocks[slot]):
+                self._alloc_block(slot, req.rid)
         logits, self._pools, self._dense = self._decode_paged(
             self.params, self._pools, self._dense,
             jnp.asarray(self._tables), jnp.asarray(self._tokens),
             jnp.asarray(self._pos))
         return logits
 
-    def _retire(self, slot: int) -> None:
-        req = self._slot_req[slot]
-        super()._retire(slot)
-        self._pool.release(req.rid, self._slot_blocks[slot])
-        self._slot_blocks[slot] = []
-        self._tables[slot, :] = 0          # inactive slots target null
+    def step(self, now: int = 0) -> bool:
+        """One scheduler tick: admit what fits, then decode every active
+        slot once.  Returns True if any work (admission or decode) ran."""
+        with jax.profiler.TraceAnnotation("repro.serve.step",
+                                          queued=len(self._queue)) as span:
+            calls = self.stats.prefill_calls
+            admitted = 0
+            for slot in range(self.n_slots):
+                if self._slot_req[slot] is not None or not self._queue:
+                    continue
+                if self._queue[0].arrival > now:
+                    break                  # FIFO: don't jump future arrivals
+                if self._pool.available < self._blocks_needed(self._queue[0]):
+                    break                  # FIFO: wait for blocks to free
+                self._admit(self._queue.pop(0), slot)
+                admitted += 1
+            active = [s for s in range(self.n_slots)
+                      if self._slot_req[s] is not None]
+            self.stats.state_bytes = len(active) * self._slot_state_bytes
+            # live: the positions the decode attends over, summed
+            span.set_metadata(
+                admitted=admitted, chunks=self.stats.prefill_calls - calls,
+                active=len(active),
+                live=int(self._pos[active].sum()) + len(active),
+                state_bytes=self.stats.state_bytes)
+            if not active:
+                return False
+            with jax.profiler.TraceAnnotation(
+                    "repro.serve.decode", **self._decode_reads(active)):
+                logits = self._decode_active()
+            with jax.profiler.TraceAnnotation("repro.serve.sync"):
+                # decode keys live in the upper uint32 half; prefill keys
+                # (folded by rid) in the lower — disjoint streams from one
+                # seed
+                key = jax.random.fold_in(
+                    self._key, 0x80000000 + self.stats.decode_steps)
+                sampled = np.asarray(self._sample(logits, key))[:, 0]
+            self.stats.decode_steps += 1
+            self.stats.slot_steps += len(active)
+            with jax.profiler.TraceAnnotation("repro.serve.emit"):
+                for slot in active:
+                    self._pos[slot] += 1
+                    tok = int(sampled[slot])
+                    self._tokens[slot, 0] = tok
+                    self._emit(slot, tok)
+            return True
+
+    def run(self, requests=None) -> list:
+        """Drain the queue (plus ``requests``: ``(tokens, max_new)`` or
+        ``(tokens, max_new, arrival)`` tuples); returns one ``(n_i,)``
+        token array per request in submission order."""
+        for r in requests or ():
+            self.submit(*r)
+        self._queue.sort(key=lambda r: (r.arrival, r.rid))
+        now = 0
+        while self._queue or any(r is not None for r in self._slot_req):
+            wall = time.perf_counter()
+            for r in self._queue:
+                if r.arrival > now:
+                    break                  # queue is arrival-sorted
+                self.req_times[r.rid].setdefault("visible", wall)
+            self.step(now)
+            now += 1
+        out = [self._outputs[rid] for rid in self._order]
+        self._order = []
+        self._outputs = {}
+        return out
+
+    def step_weights(self) -> dict:
+        """Observed step mix of everything run so far, keyed like
+        ``compiled_steps()``: ``{"decode": n_decode_steps,
+        "prefill_chunk@bs": n_chunks}``.  Pass straight to
+        ``MultiSweepResult.predicted_speedup(weights=...)`` (or hand the
+        engine itself to ``weights=``: ``_weights`` calls this) so the
+        advisor prices the deployment under its ACTUAL load instead of
+        one-prefill-one-decode uniformity."""
+        return {"decode": float(self.stats.decode_steps),
+                **{k: float(v)
+                   for k, v in self.stats.prefills_by_bucket.items()}}
 
     # ------------------------------------------------------ advisor bridge
-    def compiled_steps(self, buckets=None) -> dict:
+    def compiled_steps(self) -> dict:
         """Every step this deployment runs, compiled without executing:
         the paged decode and the single chunk-prefill step, for every
-        arch (``buckets`` is ignored: there are none)."""
+        arch, keyed ``"decode"`` / ``"prefill_chunk@bs"``.  This is the
+        input to ``repro.core.price(engine, grid)``: price ALL the
+        deployment's collectives under one scenario grid in one batched
+        super-bundle evaluation."""
         p_struct = jax.tree.map(
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), self.params)
         pools, dense, tables, tokens, pos = self._step_structs()

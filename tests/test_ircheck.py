@@ -19,7 +19,7 @@ import pytest
 from repro.analysis import ircheck as irc
 
 F32 = jnp.float32
-BUILTIN_ENTRIES = {"serve.decode", "serve.prefill", "serve.write",
+BUILTIN_ENTRIES = {"serve.paged_decode", "serve.paged_prefill_chunk",
                    "sweep.price_grid_jax", "sweep.price_topk_chunk",
                    "train.step"}
 

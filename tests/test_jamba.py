@@ -17,8 +17,7 @@ import pytest
 from repro.models import blocks, mamba, reference
 from repro.models.config import ArchConfig
 from repro.models.factory import make_model
-from repro.serve import PagedContinuousEngine
-from repro.serve.scheduler import Request
+from repro.serve import PagedContinuousEngine, Request
 
 ROOT = Path(__file__).resolve().parent.parent
 #: Jamba's layout at a small width: mamba at even layers, attention (one

@@ -1,16 +1,16 @@
-"""Paged-KV continuous batching tests: greedy parity with the dense
-engines (pinned acceptance tests, exact + staggered arrivals + chunked
-SSM / hybrid prefill), KV-bytes scaling with actual sequence lengths,
-block free/reuse after eos retirement, pool-exhaustion admission errors,
-and backpressure."""
+"""Paged-KV tests of the continuous engine: greedy parity with the static
+engine (pinned acceptance test, chunked SSM / hybrid prefill), KV-bytes
+scaling with actual sequence lengths, block free/reuse after eos
+retirement, the decode's block reads, pool-exhaustion admission errors,
+and backpressure.  Staggered arrivals, eos retirement and chunk-boundary
+prompts on every arch are in ``test_scheduler.py``."""
 import jax
 import numpy as np
 import pytest
 
 from repro.configs import ARCHS
 from repro.models.factory import make_model
-from repro.serve import (ContinuousEngine, PagedContinuousEngine,
-                         PoolExhausted, ServeEngine)
+from repro.serve import PagedContinuousEngine, PoolExhausted, ServeEngine
 
 CFG = ARCHS["qwen2.5-3b"].reduced()
 KEY = jax.random.PRNGKey(0)
@@ -55,26 +55,9 @@ def test_paged_matches_static_greedy(model_params, static):
     assert eng.stats.prefills_by_bucket == {f"prefill_chunk@{BS}": 4}
 
 
-def test_paged_matches_dense_continuous_staggered(model_params):
-    """Staggered arrivals with slot reuse: the paged engine emits the same
-    tokens as the dense ContinuousEngine, request for request."""
-    model, params = model_params
-    prompts = _prompts(2, 4, 7)
-    dense = ContinuousEngine(model=model, params=params, n_slots=2,
-                             max_len=MAX_LEN, prefill_buckets=(7,))
-    reqs = [(prompts[i], 5, 2 * i) for i in range(4)]
-    ref = dense.run(reqs)
-    eng = _paged(model_params)
-    outs = eng.run(reqs)
-    for r, o in zip(ref, outs):
-        np.testing.assert_array_equal(o, r)
-    assert eng.stats.completed == 4
-    assert eng._pool.in_use == 0              # everything released
-
-
 def test_kv_bytes_scale_with_actual_lengths(model_params):
     """KV bytes scale with the sum of ACTUAL sequence lengths rounded up
-    to the block size — not n_slots * max_len like the dense engine."""
+    to the block size — not n_slots * max_len."""
     prompts = _prompts(3, 1, 9)
     eng = _paged(model_params)
     eng.run([(prompts[0], 6)])
@@ -189,11 +172,6 @@ def test_admission_backpressure(model_params, static):
     outs = eng.run([(prompts[i], 6) for i in range(3)])
     np.testing.assert_array_equal(np.stack(outs), ref)
     assert eng._pool.peak_in_use <= 4
-
-
-def test_prefill_buckets_rejected(model_params):
-    with pytest.raises(ValueError, match="prefill_buckets"):
-        _paged(model_params, prefill_buckets=(8,))
 
 
 def test_step_weights_reflect_observed_mix(model_params):

@@ -77,27 +77,6 @@ def test_generate_eos_padding():
             assert (out[b, j:] == eos).all()   # padded after (and with) eos
 
 
-def test_prefill_last_index_matches_exact_length():
-    """Bucketed prefill: right-padding the prompt and gathering logits at
-    last_index reproduces the exact-length prefill logits (causal attention
-    keeps real positions independent of the padding)."""
-    import jax.numpy as jnp
-
-    model = make_model(CFG, moe_impl="dense")
-    params = model.init(KEY)
-    S, bucket = 6, 8
-    prompt = jax.random.randint(jax.random.PRNGKey(5), (2, S), 0,
-                                CFG.vocab_size)
-    exact, _ = jax.jit(lambda p, b: model.prefill(p, b, 16))(
-        params, {"tokens": prompt})
-    padded = jnp.pad(prompt, ((0, 0), (0, bucket - S)))
-    bucketed, _ = jax.jit(
-        lambda p, b, i: model.prefill(p, b, 16, last_index=i))(
-        params, {"tokens": padded}, jnp.full((2,), S - 1, jnp.int32))
-    np.testing.assert_allclose(np.asarray(bucketed), np.asarray(exact),
-                               rtol=2e-5, atol=2e-5)
-
-
 def test_sample_logits_temperature():
     logits = jnp.asarray([[[0.0, 10.0, 0.0]]])
     assert int(sample_logits(logits, KEY, 0.0)[0, 0]) == 1
