@@ -10,8 +10,9 @@ The spans, each named ``repro.price*``, and where they open:
 
 * ``repro.price`` — one :func:`~repro.core.price` call (``backend``,
   ``bundles``, ``scenarios``);
-* ``repro.price.pack`` — compiling and packing a bundle list into one
-  super-bundle (``calls``);
+* ``repro.price.pack`` — compiling a bundle list and packing it into one
+  super-bundle, or finding the one an earlier call packed from bundles of
+  the same content (``calls``; ``reused``: 1 when it was found);
 * ``repro.price.run`` — the jitted executor's call: enqueue, and on a miss
   of the bundle's jit cache also trace, lower and compile or cache read
   (``jit_miss``);
@@ -33,7 +34,9 @@ import sys
 
 def span(name: str, **stats):
     """A profiler span ``name`` carrying ``stats``, or a null context where
-    JAX is not imported."""
+    JAX is not imported.  Stats known only inside the span are added with
+    ``set_metadata(**stats)`` on what the ``with`` binds, where that is
+    not ``None``."""
     jax = sys.modules.get("jax")
     if jax is None:
         return contextlib.nullcontext()

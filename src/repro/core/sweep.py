@@ -60,7 +60,10 @@ scalars in the per-call path, ``(n_scenarios, n_sites)`` arrays here.
 from __future__ import annotations
 
 import dataclasses
+import hashlib
 import itertools
+import threading
+from collections import OrderedDict
 from dataclasses import dataclass
 from typing import Protocol, runtime_checkable
 
@@ -611,6 +614,46 @@ class CompiledBundle:
             cache[multiple] = out
         return out
 
+    def fingerprint(self) -> bytes:
+        """A digest of everything the bundle holds: every field in order,
+        each array by dtype, shape and bytes, each scalar by type and
+        value, nested dataclasses (traffic, counters) field by field.
+        Bundles with equal fingerprints price identically, whichever
+        object holds the arrays.  Cached on the bundle, under the rule
+        its jit cache relies on too: a compiled bundle is not mutated.
+        """
+        fp = getattr(self, "_fingerprint", None)
+        if fp is None:
+            h = hashlib.blake2b(digest_size=32)
+            _digest(h, self)
+            fp = h.digest()
+            object.__setattr__(self, "_fingerprint", fp)
+        return fp
+
+
+def _digest(h, x) -> None:
+    """Feed ``x`` into hash ``h`` as length-prefixed chunks, so no two
+    different values give the same byte stream."""
+    def put(b: bytes) -> None:
+        h.update(len(b).to_bytes(8, "little"))
+        h.update(b)
+
+    if dataclasses.is_dataclass(x):
+        put(type(x).__qualname__.encode())
+        for f in dataclasses.fields(x):
+            put(f.name.encode())
+            _digest(h, getattr(x, f.name))
+    elif isinstance(x, (tuple, list)):
+        put(f"{type(x).__name__}[{len(x)}]".encode())
+        for v in x:
+            _digest(h, v)
+    elif hasattr(x, "__array__"):
+        a = np.ascontiguousarray(x)
+        put(f"{a.dtype.str}{a.shape}".encode())
+        put(a.tobytes())
+    else:
+        put(f"{type(x).__qualname__}:{x!r}".encode())
+
 
 def compile_bundle(bundle: TraceBundle) -> CompiledBundle:
     """Lower a bundle to packed arrays (site order = dict insertion order,
@@ -1072,6 +1115,11 @@ def concat_bundles(bundles) -> CompiledBundle:
     ``baseline_runtime_ns`` of the super-bundle is the SUM of the parts
     (one execution of each step); per-bundle projections should use the
     per-bundle ``SweepResult``s that ``sweep_run_many`` unpacks.
+
+    Each call builds a new super-bundle with an empty jit cache; the
+    multi-bundle core instead takes its super-bundles from
+    :func:`_super_bundle`, which keeps them, and their compiled
+    executables, across calls.
     """
     from .traces import CounterSet
 
@@ -1243,6 +1291,35 @@ class MultiSweepResult:
         return [float(v) for v in w]
 
 
+#: Super-bundles of the most recent distinct bundle lists, keyed by the
+#: tuple of their parts' fingerprints, least recently used first.
+_SUPER_BUNDLES: OrderedDict = OrderedDict()
+_SUPER_BUNDLES_MAX = 8
+_SUPER_BUNDLES_LOCK = threading.Lock()
+
+
+def _super_bundle(cbs) -> tuple:
+    """``(super-bundle, reused)`` for the compiled bundles ``cbs``: the
+    super-bundle an earlier call packed from bundles of the same content
+    in the same order (``reused`` True), else a new one from
+    :func:`concat_bundles`.  A reused super-bundle brings its jit cache,
+    so the executor runs the executable it already traced and loaded;
+    equal fingerprints mean equal closed-over constants, so that is the
+    executable a fresh trace would build.  The cache holds the last
+    ``_SUPER_BUNDLES_MAX`` distinct lists, so a process pricing ever-new
+    bundle sets holds a bounded number of executables."""
+    key = tuple(cb.fingerprint() for cb in cbs)
+    with _SUPER_BUNDLES_LOCK:
+        super_cb = _SUPER_BUNDLES.get(key)
+        if super_cb is not None:
+            _SUPER_BUNDLES.move_to_end(key)
+            return super_cb, True
+        super_cb = _SUPER_BUNDLES[key] = concat_bundles(cbs)
+        if len(_SUPER_BUNDLES) > _SUPER_BUNDLES_MAX:
+            _SUPER_BUNDLES.popitem(last=False)
+        return super_cb, False
+
+
 def _sweep_plan_many(bundles, grid, plan: ExecPlan | None, names=None,
                      mpi_transfer=None, free_transfer=None
                      ) -> MultiSweepResult:
@@ -1252,7 +1329,13 @@ def _sweep_plan_many(bundles, grid, plan: ExecPlan | None, names=None,
     its own matrices: writable float64 ``(S, c_b)``, unchunked the
     F-ordered views of one call-major ``(c_b, S)`` block per field, in
     one float64 pass over the executor's output.  Bundles share no
-    memory."""
+    memory.
+
+    The super-bundle, and with it the jitted executable in its jit cache,
+    comes from :func:`_super_bundle`: a list whose bundles hold what an
+    earlier call's held, in the same order, reuses that call's, so
+    re-pricing a bundle set under new scenarios traces nothing again.
+    The ``repro.price.pack`` span's ``reused`` says which it was."""
     if plan is not None and is_streaming(plan.backend):
         raise ValueError(
             f"backend {plan.backend!r} is a streaming reducer and returns "
@@ -1267,10 +1350,12 @@ def _sweep_plan_many(bundles, grid, plan: ExecPlan | None, names=None,
 
     calls = sum(b.n_calls if isinstance(b, CompiledBundle)
                 else len(b.call_sites) for b in bundles)
-    with span("repro.price.pack", calls=calls):
+    with span("repro.price.pack", calls=calls) as sp:
         cbs = [b if isinstance(b, CompiledBundle) else compile_bundle(b)
                for b in bundles]
-        super_cb = concat_bundles(cbs)
+        super_cb, reused = _super_bundle(cbs)
+        if sp is not None:              # None where JAX is not imported
+            sp.set_metadata(reused=int(reused))
     plan = (plan if plan is not None else ExecPlan()).resolved()
     parts = _price_matrices(super_cb, grid, plan,
                             [cb.n_calls for cb in cbs],

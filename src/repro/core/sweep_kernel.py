@@ -290,7 +290,11 @@ def _jitted_price(cb, key, make_run):
     The cache lives ON the bundle (attached via ``object.__setattr__`` —
     it's a frozen dataclass), so the jitted executables and the closed-over
     arrays die with the bundle instead of accumulating in a module-level
-    registry for the process lifetime.
+    registry for the process lifetime.  The super-bundle of a multi-bundle
+    price is the one bundle kept beyond its call: ``sweep._super_bundle``
+    holds those of the last few distinct bundle lists, a fixed number, so
+    re-pricing a list runs its executable again without a retrace while
+    a process pricing ever-new lists still holds a bounded set.
 
     Returns ``(fn, miss)``: ``miss`` is True when this call built a new
     ``jax.jit`` (its first call then traces and lowers again).

@@ -16,6 +16,7 @@ from repro.configs import ARCHS
 from repro.core import (ModelParams, ParamGrid, adaptive_sample,
                         compile_bundle, price)
 from repro.core.spans import span
+from repro.core.sweep import _SUPER_BUNDLES
 from repro.models.factory import make_model
 from repro.serve import PagedContinuousEngine
 from test_sweep_backends import small_bundle
@@ -61,6 +62,7 @@ def recorded(tmp_path_factory):
                              cxl_lat_ns=[300.0, 500.0],
                              cxl_atomic_lat_ns=[350.0, 550.0, 750.0])
     bundles = [small_bundle(seed=3), small_bundle(seed=4, n_sites=2)]
+    _SUPER_BUNDLES.clear()      # no super-bundle from an earlier test
     cb = compile_bundle(small_bundle(seed=5))
     design = adaptive_sample(ModelParams.multinode(), 40, seed=2,
                              cxl_lat_ns=(250.0, 700.0))
@@ -121,7 +123,7 @@ def _named(spans, name):
     return [s for s in spans if s.name == name]
 
 
-def test_price_list_spans_nest_and_miss_every_call(recorded):
+def test_price_list_spans_nest_and_reuse_the_packed_plan(recorded):
     spans, grid = recorded["by"]["list"], recorded["grid"]
     tops = _named(spans, "repro.price")
     assert len(tops) == 2
@@ -132,10 +134,13 @@ def test_price_list_spans_nest_and_miss_every_call(recorded):
         assert [s.name for s in inner] == [
             "repro.price.pack", "repro.price.run", "repro.price.fetch",
             "repro.price.split"]
-        assert inner[0].stats == {"calls": 5}
-    # each call packs a new super-bundle, so its jit is built again
+    # the TraceBundles compile afresh on every call; the second call's hold
+    # what the first call's held, so it reuses that call's super-bundle and
+    # the jit it built
+    assert [s.stats for s in _named(spans, "repro.price.pack")] == [
+        {"calls": 5, "reused": 0}, {"calls": 5, "reused": 1}]
     assert [s.stats["jit_miss"] for s in _named(spans, "repro.price.run")] \
-        == [1, 1]
+        == [1, 0]
 
 
 def test_fetch_counts_the_float64_written_once(recorded):

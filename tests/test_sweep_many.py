@@ -335,3 +335,100 @@ def test_advisor_sweep_text_many_costs_alignment():
     assert multi["a"].call_ids == adv.sweep_text(SYNTH_HLO_A, grid).call_ids
     with pytest.raises(ValueError):            # dict costs need named steps
         adv.sweep_text_many([SYNTH_HLO_A], grid, costs={"a": {}})
+
+
+@pytest.fixture
+def packs(monkeypatch):
+    """Start from an empty super-bundle cache and record, per priced
+    list, whether its super-bundle was reused (``"reused"``) and, per
+    executor call, whether it built a new jit (``"jit_miss"``)."""
+    from repro.core import sweep, sweep_kernel
+    seen = {"reused": [], "jit_miss": []}
+    super_bundle, run_jitted = sweep._super_bundle, sweep_kernel._run_jitted
+
+    def spy_super(cbs):
+        out = super_bundle(cbs)
+        seen["reused"].append(int(out[1]))
+        return out
+
+    def spy_run(fn, miss, x64, *args):
+        seen["jit_miss"].append(int(miss))
+        return run_jitted(fn, miss, x64, *args)
+
+    monkeypatch.setattr(sweep, "_super_bundle", spy_super)
+    monkeypatch.setattr(sweep_kernel, "_run_jitted", spy_run)
+    sweep._SUPER_BUNDLES.clear()
+    yield seen
+    sweep._SUPER_BUNDLES.clear()
+
+
+@pytest.mark.parametrize("backend", ("jax", "pallas"))
+def test_equal_bundles_reuse_the_packed_plan(bundles, grid, packs, backend):
+    """Bundles compiled afresh, equal in content, reuse the super-bundle
+    and its jit: no new jit and no retrace, and results bit-identical to
+    a run that starts from an empty cache."""
+    from repro.core.sweep import _SUPER_BUNDLES
+    price([compile_bundle(b) for b in bundles], grid, plan=backend)
+    (super_cb,) = _SUPER_BUNDLES.values()
+    again = price([compile_bundle(b) for b in bundles], grid, plan=backend)
+    assert packs == {"reused": [0, 1], "jit_miss": [1, 0]}
+    assert list(_SUPER_BUNDLES.values()) == [super_cb]
+    (fn,) = super_cb._jit_cache.values()
+    assert fn._cache_size() == 1
+    _SUPER_BUNDLES.clear()
+    cold = price(bundles, grid, plan=backend)
+    assert packs == {"reused": [0, 1, 0], "jit_miss": [1, 0, 1]}
+    for rw, rc in zip(again, cold):
+        for f in MATRIX_FIELDS:
+            np.testing.assert_array_equal(getattr(rw, f), getattr(rc, f))
+
+
+def test_one_changed_weight_is_a_new_plan(bundles, grid, packs):
+    """A bundle whose one sample weight differs has another fingerprint:
+    its list packs anew and misses the jit, and the changed bundle prices
+    as its own ``sweep_run`` does, not as the bundle it was built from."""
+    import dataclasses
+    cbs = [compile_bundle(b) for b in bundles]
+    price(cbs, grid, plan="jax")
+    changed = make_bundle(1, 2, 900.0, 2.5e9)
+    samples = changed.call("b1_recv1").samples
+    samples[-1] = dataclasses.replace(samples[-1],
+                                      weight=2.0 * samples[-1].weight)
+    ccb = compile_bundle(changed)
+    assert ccb.fingerprint() != cbs[1].fingerprint()
+    multi = price([cbs[0], ccb, cbs[2]], grid, plan="jax")
+    assert packs == {"reused": [0, 0], "jit_miss": [1, 1]}
+    _assert_matches(multi, [sweep_run(b, grid, plan=ExecPlan("jax"))
+                            for b in (cbs[0], ccb, cbs[2])], "changed")
+    old = sweep_run(cbs[1], grid, plan=ExecPlan("jax"))
+    assert any(not np.array_equal(getattr(multi[1], f), getattr(old, f))
+               for f in MATRIX_FIELDS)
+
+
+def test_another_order_is_another_plan(bundles, grid, packs):
+    """The key is ordered: the same bundles reversed pack anew, and each
+    bundle's result is still its own."""
+    cbs = [compile_bundle(b) for b in bundles]
+    price(cbs, grid, plan="jax")
+    multi = price(cbs[::-1], grid, plan="jax")
+    assert packs == {"reused": [0, 0], "jit_miss": [1, 1]}
+    _assert_matches(multi, [sweep_run(b, grid, plan=ExecPlan("jax"))
+                            for b in cbs[::-1]], "reversed")
+
+
+def test_super_bundle_cache_is_bounded(bundles, grid, packs):
+    """After more distinct lists than the cache holds, the first list's
+    super-bundle is gone and it packs anew; a recent one is still kept."""
+    from repro.core.sweep import _SUPER_BUNDLES, _SUPER_BUNDLES_MAX
+    cbs = [compile_bundle(b) for b in bundles]
+    lists = [list(p) for n in (3, 2, 1)
+             for p in itertools.permutations(cbs, n)]
+    assert len(lists) > _SUPER_BUNDLES_MAX + 1
+    lists = lists[:_SUPER_BUNDLES_MAX + 1]
+    for cb_list in lists:
+        price(cb_list, grid)
+    assert len(_SUPER_BUNDLES) == _SUPER_BUNDLES_MAX
+    price(lists[-1], grid)
+    price(lists[0], grid)
+    assert packs["reused"] == [0] * len(lists) + [1, 0]
+    assert len(_SUPER_BUNDLES) == _SUPER_BUNDLES_MAX
